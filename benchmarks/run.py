@@ -34,6 +34,8 @@ def main() -> None:
                          "implies running the 'ivf' sweep")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bandit_online, fault_recovery, fig1_locality,
                    gateway_load, intrinsic_dim, ivf_recall, seed_stability,
                    serving_latency, table2_text_auc, table3_latency,

@@ -1,4 +1,4 @@
-"""qwen3-4b [dense] — qk-norm + GQA.  [hf:Qwen/Qwen3-8B]"""
+"""qwen3-4b [dense] — qk-norm + GQA.  [hf:Qwen/Qwen3-4B]"""
 from .base import ATTN_DENSE, ModelConfig
 
 CONFIG = ModelConfig(

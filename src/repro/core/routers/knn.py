@@ -566,8 +566,7 @@ class KNNRouter(Router):
             key = ("exact", k, self.use_pallas)
             if self._dev.get("search_key") != key:
                 self._dev["search"] = functools.partial(
-                    knn_topk.__wrapped__, k=k, use_pallas=self.use_pallas,
-                    interpret=True)
+                    knn_topk.__wrapped__, k=k, use_pallas=self.use_pallas)
                 self._dev["search_key"] = key
             Xd = self._dev.get("X")
             if Xd is None or Xd.shape != self._X.shape:
@@ -731,7 +730,6 @@ class KNNRouter(Router):
         index arrays are `device_put` onto the mesh ONCE per index version
         — passing host-committed arrays straight in would re-replicate tens
         of MB on every call, which is slower than not sharding at all."""
-        import jax.experimental.shard_map as shmap
         from jax.sharding import NamedSharding, PartitionSpec as P
         key = ("qmesh", qmesh, search, self.weights, self.temperature)
         cached = self._dev.get("qmesh_fn")
@@ -747,10 +745,10 @@ class KNNRouter(Router):
             specs = (P(axes), P(axes)) + tuple(P() for _ in args) + (P(), P(),
                                                                      P())
             # repro: allow-jit-cache: cached in self._dev under `key` above
-            cached = jax.jit(shmap.shard_map(
+            cached = jax.jit(jax.shard_map(
                 local, mesh=qmesh, in_specs=specs,
                 out_specs=tuple(P(axes) for _ in range(5)),
-                check_rep=False))
+                check_vma=False))
             self._dev["qmesh_fn"] = cached
             self._dev["qmesh_key"] = key
         rep = NamedSharding(qmesh, P())

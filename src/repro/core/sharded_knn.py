@@ -36,8 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.knn_ivf.ops import (DEFAULT_NPROBE, DEFAULT_RERANK,
                                        DynamicIVFIndex, IVFIndex, IVFPQIndex,
@@ -112,7 +112,7 @@ def sharded_knn_topk(queries, support, k: int, mesh: Mesh,
     sup3 = support.reshape(n_shards, rows_per, support.shape[1])
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(), P(axes, None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     with mesh:
         return fn(queries, sup3)
 
@@ -181,7 +181,7 @@ def sharded_ivf_topk(queries, index: IVFIndex, k: int, mesh: Mesh,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(), P(), P(axes, None, None, None),
                              P(axes, None, None), P(axes, None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     with mesh:
         return fn(queries, index.centroids, sup4, ids3, inv3)
 
@@ -274,7 +274,7 @@ def sharded_ivfpq_topk(queries, index: IVFPQIndex, k: int, mesh: Mesh,
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(), P(), P(), P(), P(axes, None, None, None),
                              P(axes, None, None), P(axes, None, None)),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     with mesh:
         sc, ix = fn(queries, index.centroids, anchors, index.codebooks,
                     codes4, ids3, inv3)

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG = -3.0e38
 
 
@@ -61,7 +63,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 
 def decode_attention_pallas(q, cache_k, cache_v, pos, *, ring=False,
-                            block_k=512, interpret=True):
+                            block_k=512):
     """q: (B, KV, G, hd); cache_k/v: (B, KV, S, hd); pos scalar int32."""
     B, KV, G, hd = q.shape
     S = cache_k.shape[2]
@@ -91,5 +93,5 @@ def decode_attention_pallas(q, cache_k, cache_v, pos, *, ring=False,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(jnp.atleast_1d(pos).astype(jnp.int32), q, cache_k, cache_v)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG = -3.0e38
 
 
@@ -88,7 +90,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=0,
-                           block_q=128, block_k=128, interpret=True):
+                           block_q=128, block_k=128):
     """q: (B, H, Sq, hd); k, v: (B, KV, Sk, hd) -> (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -117,5 +119,5 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(q, k, v)

@@ -1,8 +1,9 @@
 """Measured autotuning of the retrieval kernels' tile/block constants.
 
-The kernels ship hand-picked defaults — ``lane_pad=8`` list padding in the
-builders, ``block_q=32`` query tiles in the tiles/Pallas plan, a
-single-chunk fused ADC scan — that were chosen for one machine and one
+The kernels ship hand-picked defaults — the platform's ``lane_pad`` list
+padding in the builders (`ops.default_lane_pad`), ``block_q=32`` query
+tiles in the tiles/Pallas plan, a single-chunk fused ADC scan — that were
+chosen for one machine and one
 shape.  This module replaces them with *measured* choices: each candidate
 constant is timed on the caller's real index and query shapes, and the
 compiled HLO's roofline terms (FLOPs / bytes-accessed from
@@ -105,7 +106,7 @@ def _staged_candidate(index, queries, k: int, nprobe: int, rerank: int,
             jnp.asarray(inv_order), index.codes_cm, index.ids_cm,
             index.inv_cm, index.anchors, index.codebooks, index.sup_flat,
             k=kc, kk=kk, bq=bq, m=index.m, nbits=index.nbits,
-            rerank=bool(rerank), backend="tiles", interpret=True)
+            rerank=bool(rerank), backend="tiles")
     else:
         terms = roofline_terms(
             ops._score_tiles, q_sorted, jnp.asarray(qp_sorted),

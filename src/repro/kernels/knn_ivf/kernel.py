@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from ..knn_topk.kernel import NEG, merge_topk
 
 
@@ -42,10 +43,10 @@ def _ivf_kernel(probe_ref, valid_ref, q_ref, qp_ref, s_ref, ids_ref,
         cid = probe_ref[i, p]
         q = q_ref[...].astype(jnp.float32)                   # (BQ, D)
         s = s_ref[0].astype(jnp.float32)                     # (L, D)
-        ids = ids_ref[...]                                   # (1, L)
+        ids = ids_ref[0]                                     # (1, L)
         sims = jax.lax.dot_general(q, s, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
-        sims = sims * inv_ref[...]                           # (BQ, L)
+        sims = sims * inv_ref[0]                             # (BQ, L)
         probed = jnp.any(qp_ref[...] == cid, axis=1)         # (BQ,)
         ok = probed[:, None] & (ids >= 0)                    # (BQ, L)
         sims = jnp.where(ok, sims, NEG)
@@ -63,7 +64,7 @@ def _ivf_kernel(probe_ref, valid_ref, q_ref, qp_ref, s_ref, ids_ref,
 
 
 def ivf_topk_pallas(queries, sup_cm, ids_cm, inv_cm, q_probe, tile_probe,
-                    tile_valid, k: int, *, interpret: bool = True):
+                    tile_valid, k: int):
     """queries (Q, D) L2-normalized, Q a multiple of the tile size BQ implied
     by tile_probe (T = Q/BQ); sup_cm (C, L, D); ids_cm (C, L) i32;
     inv_cm (C, L) precomputed inverse row norms (0 on padding);
@@ -87,10 +88,10 @@ def ivf_topk_pallas(queries, sup_cm, ids_cm, inv_cm, q_probe, tile_probe,
             pl.BlockSpec((bq, P), lambda i, p, probe, valid: (i, 0)),
             pl.BlockSpec((1, L, D),
                          lambda i, p, probe, valid: (probe[i, p], 0, 0)),
-            pl.BlockSpec((1, L),
-                         lambda i, p, probe, valid: (probe[i, p], 0)),
-            pl.BlockSpec((1, L),
-                         lambda i, p, probe, valid: (probe[i, p], 0)),
+            pl.BlockSpec((1, 1, L),
+                         lambda i, p, probe, valid: (probe[i, p], 0, 0)),
+            pl.BlockSpec((1, 1, L),
+                         lambda i, p, probe, valid: (probe[i, p], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, p, probe, valid: (i, 0)),
@@ -104,6 +105,8 @@ def ivf_topk_pallas(queries, sup_cm, ids_cm, inv_cm, q_probe, tile_probe,
             jax.ShapeDtypeStruct((Q, k), jnp.float32),
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
-        interpret=interpret,
-    )(tile_probe, tile_valid, queries, q_probe, sup_cm, ids_cm, inv_cm)
+        interpret=kernels.interpret_mode(),
+    )(tile_probe, tile_valid, queries, q_probe, sup_cm,
+      # (C, 1, L): a per-cluster (1, L) row is then a whole trailing block
+      ids_cm[:, None, :], inv_cm[:, None, :])
     return out_s, out_i

@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import persist
+from repro import kernels, persist
 from . import pq as pqmod
 from .kernel import ivf_topk_pallas
 from .pq_kernel import ivfpq_adc_pallas
@@ -76,9 +76,13 @@ DEFAULT_NPROBE = 8
 # gaps shrink while quantization error does not, so the shortlist needs
 # headroom — 8x restores recall@100 > 0.95 at m=D/4 (benchmarks/ivf_recall)
 DEFAULT_RERANK = 8
-# default list-length rounding; pass lane_pad=128 to the builders for
-# compiled (non-interpret) TPU runs so every list is lane-aligned
-_LANE_PAD = 8
+
+
+def default_lane_pad() -> int:
+    """List-length rounding the builders use when none is given: 128
+    lane-aligns every list where the Pallas kernels compile (TPU), 8 keeps
+    interpreted (CPU) indexes compact."""
+    return 8 if kernels.interpret_mode() else 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,20 +205,12 @@ class IVFPQIndex:
         inv[self.ids_h[self.ids_h >= 0]] = self.inv_h[self.ids_h >= 0]
         return jnp.asarray(inv)
 
-    @functools.cached_property
-    def cb_mat(self) -> jnp.ndarray:
-        """Block-diagonal ``(m*2^nbits, D)`` codebook expansion, derived
-        lazily — only the Pallas ADC path reads it (the one-matmul in-kernel
-        LUT build); host/tiles scans never materialize it."""
-        return jnp.asarray(pqmod.expand_codebooks(self.codebooks_h))
-
     @property
     def index_bytes(self) -> int:
         """Hot (per-probe-scanned) storage: packed codes + ids + norms +
         centroids + anchors + codebooks.  ``sup_flat`` is the cold re-rank
         tier and is NOT counted — it is touched only for ~rerank*k rows per
-        query and can live off-device; the derived ``cb_mat`` scratch
-        (Pallas path only) is likewise excluded."""
+        query and can live off-device."""
         return int(self.codes_h.nbytes + self.ids_h.nbytes + self.inv_h.nbytes
                    + np.asarray(self.centroids).nbytes + self.anchors_h.nbytes
                    + self.codebooks_h.nbytes)
@@ -291,13 +287,14 @@ def _balanced_lists(xn: np.ndarray, assign: np.ndarray, n_clusters: int,
 
 
 def _coarse_partition(sup: np.ndarray, n_clusters: int | None, seed: int,
-                      iters: int, balance: float, lane_pad: int):
+                      iters: int, balance: float, lane_pad: int | None):
     """Shared front half of both index builders: spherical k-means +
     principal-direction balancing/relabelling.  Returns (centroids (C, D)
     unit-norm, member-row lists ordered along the centroids' top principal
     direction, padded list length, per-row norms (N, 1))."""
     n, d = sup.shape
     c = min(n_clusters or default_n_clusters(n), n)
+    lane_pad = lane_pad or default_lane_pad()
     norms = np.maximum(np.linalg.norm(sup, axis=1, keepdims=True), 1e-12)
     xn = sup / norms
     cent, assign = _spherical_kmeans(xn, c, seed, iters)
@@ -324,14 +321,13 @@ def _coarse_partition(sup: np.ndarray, n_clusters: int | None, seed: int,
 
 def build_ivf_index(support, n_clusters: int | None = None, seed: int = 0,
                     iters: int = 10, balance: float = 1.5,
-                    lane_pad: int = _LANE_PAD) -> IVFIndex:
+                    lane_pad: int | None = None) -> IVFIndex:
     """support (N, D) raw rows (normalized internally for clustering only —
     scoring keeps the raw rows so results match `knn_topk` bit-for-bit).
     ``n_clusters`` is a TARGET: oversized k-means cells are split until no
     list exceeds ``balance * N/n_clusters`` rows, so the final cluster count
     can be somewhat higher.  ``lane_pad`` rounds the padded list length (and
-    floors the balance cap): 8 keeps interpret-mode/CPU indexes compact,
-    128 lane-aligns every list for compiled TPU runs."""
+    floors the balance cap); None takes the platform's `default_lane_pad`."""
     sup = np.asarray(support, np.float32)
     n, d = sup.shape
     centroids, lists, lsz, norms = _coarse_partition(
@@ -368,7 +364,7 @@ def assemble_ivfpq(centroids: np.ndarray, anchors: np.ndarray,
 def build_ivfpq_index(support, n_clusters: int | None = None,
                       m: int | None = None, nbits: int = 8, seed: int = 0,
                       iters: int = 10, balance: float = 1.5,
-                      lane_pad: int = _LANE_PAD,
+                      lane_pad: int | None = None,
                       pq_iters: int = 8) -> IVFPQIndex:
     """IVF-PQ index build: the identical coarse partition as
     `build_ivf_index` (same k-means seed path -> same lists, so recall
@@ -622,10 +618,10 @@ class DynamicIVFIndex:
                 rows, n_clusters=kw.get("n_clusters"),
                 m=kw.get("m", base.m),           # keep the base's geometry
                 nbits=kw.get("nbits", base.nbits),
-                seed=kw.get("seed", 0), lane_pad=kw.get("lane_pad", _LANE_PAD))
+                seed=kw.get("seed", 0), lane_pad=kw.get("lane_pad"))
         return build_ivf_index(
             rows, n_clusters=kw.get("n_clusters"), seed=kw.get("seed", 0),
-            lane_pad=kw.get("lane_pad", _LANE_PAD))
+            lane_pad=kw.get("lane_pad"))
 
     def recluster(self, sync: bool = True) -> None:
         """Re-train the coarse partition (and PQ codebooks on residuals) over
@@ -1023,9 +1019,7 @@ def _adc_tiles(queries, q_probe, tile_probe, tile_valid, codes_cm, ids_cm,
     kk = 2 ** nbits
 
     qf = queries.astype(jnp.float32)
-    lut = jnp.einsum("qmd,mkd->qmk", qf.reshape(qp, m, d // m), codebooks,
-                     preferred_element_type=jnp.float32)
-    lut = lut.reshape(t, bq, m * kk)
+    lut = _adc_lut(qf, codebooks, m, nbits).reshape(t, bq, m * kk)
 
     codes = pqmod.unpack_codes_jnp_cm(
         jnp.take(codes_cm, tile_probe, axis=0), m, nbits)   # (T, S, m, L)
@@ -1143,13 +1137,14 @@ def _adc_probe_scan(qf, probe, lut_flat, codes_rm, ids_cm, inv_cm, anchors,
     return sims.reshape(qn, p * l), ids.reshape(qn, p * l)
 
 
-def _adc_lut_flat(qf, codebooks, m: int, nbits: int):
-    """Flattened per-query ADC tables (Q * m * 2^nbits,) for the one-take
-    gather in `_adc_probe_scan`."""
+def _adc_lut(qf, codebooks, m: int, nbits: int):
+    """Per-query ADC tables ``(Q, m * 2^nbits)`` of subvector dot products
+    — one einsum, shared by every device ADC path (the Pallas kernel takes
+    them as an input block)."""
     qn, d = qf.shape
     lut = jnp.einsum("qmd,mkd->qmk", qf.reshape(qn, m, d // m), codebooks,
                      preferred_element_type=jnp.float32)
-    return lut.reshape(qn * m * 2 ** nbits)
+    return lut.reshape(qn, m * 2 ** nbits)
 
 
 def _fused_ivf_topk_impl(queries, centroids, sup_cm, ids_cm, inv_cm,
@@ -1215,7 +1210,7 @@ def _fused_ivfpq_topk_impl(queries, centroids, codes_cm, ids_cm, inv_cm,
     dispatch policy records)."""
     qf = queries.astype(jnp.float32)
     probe = ivf_probe(qf, centroids, nprobe)
-    lut = _adc_lut_flat(qf, codebooks, m, nbits)
+    lut = _adc_lut(qf, codebooks, m, nbits).reshape(-1)  # one-take gather
     sims, ids = _adc_probe_scan(qf, probe, lut, codes_cm, ids_cm, inv_cm,
                                 anchors, m, nbits, pc)
     if not kk:
@@ -1240,7 +1235,7 @@ def _fused_dyn_ivfpq_topk_impl(queries, centroids, codes_cm, ids_cm, inv_cm,
     cost."""
     qf = queries.astype(jnp.float32)
     probe = ivf_probe(qf, centroids, nprobe)
-    lut = _adc_lut_flat(qf, codebooks, m, nbits)
+    lut = _adc_lut(qf, codebooks, m, nbits).reshape(-1)  # one-take gather
     sims_b, ids_b = _adc_probe_scan(qf, probe, lut, codes_cm, ids_cm, inv_cm,
                                     anchors, m, nbits, pc)
     sims_d, ids_d = _adc_probe_scan(qf, probe, lut, dl_codes, dl_ids, dl_inv,
@@ -1338,8 +1333,7 @@ def _fused_ivfpq_dispatch(queries, index, k: int, rerank: int, nprobe: int):
 
 def ivf_topk(queries, index: IVFIndex, k: int,
              nprobe: int = DEFAULT_NPROBE, *, use_pallas: bool = False,
-             backend: str | None = None, interpret: bool = True,
-             block_q: int = 32):
+             backend: str | None = None, block_q: int = 32):
     """queries (Q, D) L2-normalized.  Returns (scores (Q, k), indices (Q, k))
     — indices into the original support row order; slots beyond the number
     of valid candidates hold -inf / -1.
@@ -1363,7 +1357,7 @@ def ivf_topk(queries, index: IVFIndex, k: int,
             base = index.base
         base_sc, base_ix = ivf_topk(
             queries, base, k, nprobe, use_pallas=use_pallas,
-            backend=backend, interpret=interpret, block_q=block_q)
+            backend=backend, block_q=block_q)
         return index.merge_delta(queries, base_sc, base_ix, k)
     k = min(k, index.n_rows, nprobe * index.list_size)
     queries = jnp.asarray(queries)
@@ -1382,7 +1376,7 @@ def ivf_topk(queries, index: IVFIndex, k: int,
         scores, idx = ivf_topk_pallas(
             q_sorted, index.sup_cm, index.ids_cm, index.inv_cm,
             jnp.asarray(qp_sorted), jnp.asarray(tile_probe),
-            jnp.asarray(tile_valid), k, interpret=interpret)
+            jnp.asarray(tile_valid), k)
         scores = jnp.where(idx >= 0, scores, -jnp.inf)
     elif backend == "tiles":
         scores, idx = _score_tiles(
@@ -1398,7 +1392,7 @@ def ivf_topk(queries, index: IVFIndex, k: int,
 def ivfpq_topk(queries, index: IVFPQIndex, k: int,
                nprobe: int = DEFAULT_NPROBE, rerank: int = DEFAULT_RERANK, *,
                use_pallas: bool = False, backend: str | None = None,
-               interpret: bool = True, block_q: int = 32):
+               block_q: int = 32):
     """Two-stage IVF-PQ search.  queries (Q, D) L2-normalized; same output
     contract as `ivf_topk` (-inf / -1 beyond the valid candidates).
 
@@ -1436,7 +1430,7 @@ def ivfpq_topk(queries, index: IVFPQIndex, k: int,
             base = index.base
         base_sc, base_ix = ivfpq_topk(
             queries, base, k, nprobe, rerank, use_pallas=use_pallas,
-            backend=backend, interpret=interpret, block_q=block_q)
+            backend=backend, block_q=block_q)
         return index.merge_delta(queries, base_sc, base_ix, k)
     k = min(k, index.n_rows, nprobe * index.list_size)
     kk = min(max(rerank, 1) * k, index.n_rows, nprobe * index.list_size)
@@ -1458,32 +1452,37 @@ def ivfpq_topk(queries, index: IVFPQIndex, k: int,
     return _staged_tail(
         queries, q_sorted, jnp.asarray(qp_sorted), jnp.asarray(tile_probe),
         jnp.asarray(tile_valid), jnp.asarray(inv_order), index.codes_cm,
-        index.ids_cm, index.inv_cm, index.anchors,
-        index.cb_mat if backend == "pallas" else index.codebooks,
+        index.ids_cm, index.inv_cm, index.anchors, index.codebooks,
         index.sup_flat, k=k, kk=kk, bq=bq, m=index.m, nbits=index.nbits,
-        rerank=bool(rerank), backend=backend, interpret=interpret)
+        rerank=bool(rerank), backend=backend)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "kk", "bq", "m", "nbits",
-                                             "rerank", "backend",
-                                             "interpret"))
+                                             "rerank", "backend"))
 def _staged_tail(queries, q_sorted, qp_sorted, tile_probe, tile_valid,
-                 inv_order, codes_cm, ids_cm, inv_cm, anchors, cb,
+                 inv_order, codes_cm, ids_cm, inv_cm, anchors, codebooks,
                  sup_flat, *, k: int, kk: int, bq: int, m: int, nbits: int,
-                 rerank: bool, backend: str, interpret: bool):
+                 rerank: bool, backend: str):
     """Device tail of the tiles/pallas backends: ADC scoring, un-sort, and
     the exact re-rank in ONE jitted dispatch — after the host plans the
-    tile slot lists there is no further host->device hop.  ``cb`` is the
-    block-diagonal ``cb_mat`` for pallas, the raw codebooks for tiles."""
+    tile slot lists there is no further host->device hop."""
     if backend == "pallas":
+        qf = q_sorted.astype(jnp.float32)
+        t, s = tile_probe.shape
+        # each query's dot with the anchor of every slot of its tile
+        aq = jnp.einsum("tqd,tsd->tqs", qf.reshape(t, bq, -1),
+                        jnp.take(anchors, tile_probe, axis=0),
+                        preferred_element_type=jnp.float32)
+        lut = _adc_lut(qf, codebooks, m, nbits).reshape(t * bq, m, -1)
         scores, idx = ivfpq_adc_pallas(
-            q_sorted, codes_cm, ids_cm, inv_cm, anchors, cb, qp_sorted,
-            tile_probe, tile_valid, kk, m=m, nbits=nbits, interpret=interpret)
+            jnp.moveaxis(lut, 1, 0), codes_cm, ids_cm, inv_cm,
+            aq.reshape(t * bq, s), qp_sorted, tile_probe, tile_valid, kk,
+            m=m, nbits=nbits)
         scores = jnp.where(idx >= 0, scores, -jnp.inf)
     else:
         scores, idx = _adc_tiles(
             q_sorted, qp_sorted, tile_probe, tile_valid, codes_cm, ids_cm,
-            inv_cm, anchors, cb, kk, bq, m, nbits)
+            inv_cm, anchors, codebooks, kk, bq, m, nbits)
     scores, idx = scores[inv_order], idx[inv_order]
     if not rerank:
         return scores[:, :k], idx[:, :k]

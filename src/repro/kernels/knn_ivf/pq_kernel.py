@@ -14,25 +14,24 @@ MB-byte rows — and the grid's slot axis keeps the standard Pallas
 double-buffered pipeline (slot s+1's block streams in while slot s is
 scored).
 
-Per query tile the kernel builds the ADC lookup table ONCE into VMEM
-scratch at slot 0:
+The per-query ADC lookup tables arrive precomputed (one XLA einsum ahead
+of the kernel, `ops._adc_lut`) as an ``(m, BQ, K)`` block whose index map
+is constant across the slot axis, so it is DMA'd once per query tile.  Each
+slot's codes are widened once into an int32 VMEM scratch, then scored
+subspace by subspace in a `fori_loop`: the subspace's codes expand into an
+indicator block and contract against its table on the MXU::
 
-    lut = q @ cb_mat.T          # (BQ, m*K); cb_mat is the block-diagonal
-                                # (m*K, D) codebook expansion (pq.py), so
-                                # the table is one MXU matmul — no reshapes
+    onehot[c, l] = 1  iff  code_jl == c            # (K, L)
+    sims += lut[j] @ onehot                         # (BQ, L)
+    sims  = (sims + q @ anchor_c) * inv_norm        # exact stored norms
 
-and scores each slot's codes by expanding them into an m-hot indicator
-matrix and contracting it against the table on the MXU:
-
-    onehot[l, j*K + c] = 1  iff  code_jl == c      # (L, m*K)
-    sims = lut @ onehot.T + (q @ anchor_c)         # (BQ, L)
-
-The m-hot expansion trades FLOPs (m*K MACs/row vs m gathers) for
-Mosaic-safety — only compares, selects, and matmuls, no dynamic VMEM
-gathers — and the MXU absorbs it: the kernel stays DMA-bound, which is the
-dimension PQ improves.  Masking, the exact stored inverse norms, and the
-running (BQ, K) top-k merge are identical to the raw IVF kernel, so the
-shortlist contract (-1 ids / NEG scores in empty slots) is too.
+One subspace at a time keeps the live temporaries at ``K x L`` — the
+all-subspace ``m*K x L`` expansion outgrows VMEM at routing-embedding
+widths (m = 192 at D = 768).  Only compares, selects, matmuls and
+leading-axis ref indexing: no dynamic VMEM gathers.  Masking, the exact
+stored inverse norms, and the running (BQ, K) top-k merge are identical to
+the raw IVF kernel, so the shortlist contract (-1 ids / NEG scores in empty
+slots) is too.
 """
 from __future__ import annotations
 
@@ -43,59 +42,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from ..knn_topk.kernel import NEG, merge_topk
 
 
-def _adc_kernel(probe_ref, valid_ref, q_ref, qp_ref, cb_ref, codes_ref,
-                ids_ref, inv_ref, anch_ref, out_s_ref, out_i_ref, lut_ref, *,
-                k: int, m: int, nbits: int):
+def _adc_kernel(probe_ref, valid_ref, lut_ref, qp_ref, aq_ref, codes_ref,
+                ids_ref, inv_ref, out_s_ref, out_i_ref, codes_scr, *, k: int,
+                m: int, nbits: int):
     i = pl.program_id(0)
     p = pl.program_id(1)
     kk = 2 ** nbits
+    per_byte = 8 // nbits
 
     @pl.when(p == 0)
     def _init():
         out_s_ref[...] = jnp.full_like(out_s_ref, NEG)
         out_i_ref[...] = jnp.full_like(out_i_ref, -1)
-        # the per-tile ADC table, built once per query tile and reused by
-        # every probe slot: one (BQ, D) x (D, m*K) matmul
-        q = q_ref[...].astype(jnp.float32)
-        lut_ref[...] = jax.lax.dot_general(
-            q, cb_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
 
     @pl.when(valid_ref[i, p] != 0)
     def _merge():
         cid = probe_ref[i, p]
-        q = q_ref[...].astype(jnp.float32)                   # (BQ, D)
-        codes = codes_ref[0].astype(jnp.int32)               # (MB, L) code-major
-        ids = ids_ref[...]                                   # (1, L)
-        l = codes.shape[1]
+        # code-major block: each packed byte row is one lane vector along L
+        codes_scr[...] = codes_ref[0].astype(jnp.int32)      # (MB, L)
+        ids = ids_ref[0]                                     # (1, L)
+        l = codes_scr.shape[1]
+        code_iota = jax.lax.broadcasted_iota(jnp.int32, (kk, l), 0)
 
-        # m-hot indicator of the packed codes, accumulated subspace by
-        # subspace (static python loop — m is a compile-time constant):
-        # column j*K + c is 1 exactly when the row's j-th code equals c.
-        # The code-major block hands each subspace's codes as one LANE
-        # vector (codes[j] is contiguous along L) instead of a strided
-        # column read.
-        col = jax.lax.broadcasted_iota(jnp.int32, (l, m * kk), 1)
-        onehot = jnp.zeros((l, m * kk), jnp.float32)
-        for j in range(m):
-            if nbits == 8:
-                cj = codes[j, :]
-            else:
-                byte = codes[j // 2, :]
-                cj = (byte & 0xF) if j % 2 == 0 else ((byte >> 4) & 0xF)
-            target = cj[:, None] + j * kk                    # (L, 1)
-            onehot = onehot + jnp.where(col == target, 1.0, 0.0)
+        def subspace(j, sims):
+            byte = codes_scr[pl.ds(j // per_byte, 1), :]     # (1, L)
+            cj = (byte >> (nbits * (j % per_byte))) & (kk - 1)
+            onehot = jnp.where(code_iota == cj, 1.0, 0.0)    # (K, L)
+            return sims + jax.lax.dot_general(
+                lut_ref[j], onehot, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
 
-        sims = jax.lax.dot_general(lut_ref[...], onehot,
-                                   (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-        aq = jax.lax.dot_general(q, anch_ref[...],           # (BQ, 1)
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        sims = (sims + aq) * inv_ref[...]                    # (BQ, L)
+        sims = jax.lax.fori_loop(
+            0, m, subspace, jnp.zeros((lut_ref.shape[1], l), jnp.float32))
+
+        # the query-anchor dots of this slot's cluster: column p of the
+        # tile's (BQ, S) block, picked with a select (no dynamic lane slice)
+        aq_all = aq_ref[...]
+        slot = jax.lax.broadcasted_iota(jnp.int32, aq_all.shape, 1)
+        aq = jnp.sum(jnp.where(slot == p, aq_all, 0.0), axis=1,
+                     keepdims=True)                          # (BQ, 1)
+        sims = (sims + aq) * inv_ref[0]                      # (BQ, L)
 
         probed = jnp.any(qp_ref[...] == cid, axis=1)         # (BQ,)
         ok = probed[:, None] & (ids >= 0)                    # (BQ, L)
@@ -111,23 +102,21 @@ def _adc_kernel(probe_ref, valid_ref, q_ref, qp_ref, cb_ref, codes_ref,
         out_i_ref[...] = acc_i
 
 
-def ivfpq_adc_pallas(queries, codes_cm, ids_cm, inv_cm, anchors, cb_mat,
-                     q_probe, tile_probe, tile_valid, k: int, *, m: int,
-                     nbits: int, interpret: bool = True):
-    """queries (Q, D) L2-normalized, Q a multiple of the tile size implied
-    by tile_probe; codes_cm (C, MB, L) CODE-MAJOR packed uint8; ids_cm /
-    inv_cm (C, L); anchors (C, D) raw-space list means; cb_mat
-    (m*2^nbits, D) block-diag codebook expansion; q_probe/tile_probe/
-    tile_valid as in `ivf_topk_pallas`.  Returns the ADC shortlist
-    (scores (Q, k), indices (Q, k)) — original row ids, -1 / NEG in empty
-    slots."""
-    Q, D = queries.shape
+def ivfpq_adc_pallas(lut, codes_cm, ids_cm, inv_cm, aq, q_probe, tile_probe,
+                     tile_valid, k: int, *, m: int, nbits: int):
+    """lut (m, Q, 2^nbits) per-query ADC tables, Q a multiple of the tile
+    size implied by tile_probe; codes_cm (C, MB, L) CODE-MAJOR packed uint8;
+    ids_cm / inv_cm (C, L); aq (Q, S) each query's dot with the anchor of
+    every slot of its tile (``q @ anchors[tile_probe]``); q_probe /
+    tile_probe / tile_valid as in `ivf_topk_pallas`.  Returns the ADC
+    shortlist (scores (Q, k), indices (Q, k)) — original row ids, -1 / NEG
+    in empty slots."""
+    _, Q, KB = lut.shape
     C, MB, L = codes_cm.shape
     T, S = tile_probe.shape
     P = q_probe.shape[1]
-    MK = m * 2 ** nbits
     assert Q % T == 0, (Q, T)
-    assert cb_mat.shape == (MK, D), (cb_mat.shape, MK, D)
+    assert lut.shape == (m, Q, 2 ** nbits), (lut.shape, m, nbits)
     bq = Q // T
 
     kern = functools.partial(_adc_kernel, k=k, m=m, nbits=nbits)
@@ -135,23 +124,21 @@ def ivfpq_adc_pallas(queries, codes_cm, ids_cm, inv_cm, anchors, cb_mat,
         num_scalar_prefetch=2,
         grid=(T, S),
         in_specs=[
-            pl.BlockSpec((bq, D), lambda i, p, probe, valid: (i, 0)),
+            pl.BlockSpec((m, bq, KB), lambda i, p, probe, valid: (0, i, 0)),
             pl.BlockSpec((bq, P), lambda i, p, probe, valid: (i, 0)),
-            pl.BlockSpec((MK, D), lambda i, p, probe, valid: (0, 0)),
+            pl.BlockSpec((bq, S), lambda i, p, probe, valid: (i, 0)),
             pl.BlockSpec((1, MB, L),
                          lambda i, p, probe, valid: (probe[i, p], 0, 0)),
-            pl.BlockSpec((1, L),
-                         lambda i, p, probe, valid: (probe[i, p], 0)),
-            pl.BlockSpec((1, L),
-                         lambda i, p, probe, valid: (probe[i, p], 0)),
-            pl.BlockSpec((1, D),
-                         lambda i, p, probe, valid: (probe[i, p], 0)),
+            pl.BlockSpec((1, 1, L),
+                         lambda i, p, probe, valid: (probe[i, p], 0, 0)),
+            pl.BlockSpec((1, 1, L),
+                         lambda i, p, probe, valid: (probe[i, p], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, p, probe, valid: (i, 0)),
             pl.BlockSpec((bq, k), lambda i, p, probe, valid: (i, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((bq, MK), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((MB, L), jnp.int32)],
     )
     out_s, out_i = pl.pallas_call(
         kern,
@@ -160,7 +147,8 @@ def ivfpq_adc_pallas(queries, codes_cm, ids_cm, inv_cm, anchors, cb_mat,
             jax.ShapeDtypeStruct((Q, k), jnp.float32),
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
-        interpret=interpret,
-    )(tile_probe, tile_valid, queries, q_probe, cb_mat, codes_cm, ids_cm,
-      inv_cm, anchors)
+        interpret=kernels.interpret_mode(),
+    )(tile_probe, tile_valid, lut, q_probe, aq, codes_cm,
+      # (C, 1, L): a per-cluster (1, L) row is then a whole trailing block
+      ids_cm[:, None, :], inv_cm[:, None, :])
     return out_s, out_i

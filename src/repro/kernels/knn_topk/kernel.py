@@ -17,37 +17,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 NEG = -3.0e38  # python float: avoids captured-constant arrays in the kernel
 
 
 def merge_topk(cand_s, cand_i, k: int):
     """Running top-k over a (BQ, n_cand) candidate tile using only
-    max/select/iota ops (Mosaic-safe: no sort / no lax.top_k).  Returns the
-    (BQ, k) best scores (descending) and their candidate ids.  Shared by the
-    brute-force kernel here and the IVF kernel (`knn_ivf/kernel.py`)."""
+    max/select/iota ops (Mosaic-safe: no sort / no lax.top_k, and no gather
+    or dynamic_update_slice, neither of which lowers through Mosaic).
+    Returns the (BQ, k) best scores (descending) and their candidate ids;
+    equal scores keep their candidate order, as `lax.top_k` does.  Shared
+    by the brute-force kernel here and the IVF kernels
+    (`knn_ivf/kernel.py`, `knn_ivf/pq_kernel.py`)."""
+    n_cand = cand_s.shape[1]
     acc_s = jnp.full((cand_s.shape[0], k), NEG, cand_s.dtype)
     acc_i = jnp.full((cand_i.shape[0], k), -1, cand_i.dtype)
+    pos_iota = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, acc_s.shape, 1)
 
     def body(t, carry):
-        cs, ci, acc_s, acc_i = carry
+        cs, acc_s, acc_i = carry
         m = jnp.max(cs, axis=1, keepdims=True)                     # (BQ, 1)
-        # argmax via masked iota-max (Mosaic-safe: max/select only)
-        pos_iota = jax.lax.broadcasted_iota(jnp.int32, cs.shape, 1)
-        am = jnp.max(jnp.where(cs >= m, pos_iota, -1), axis=1,
+        # first argmax via masked iota-min (Mosaic-safe: min/select only)
+        am = jnp.min(jnp.where(cs >= m, pos_iota, n_cand), axis=1,
                      keepdims=True)                                # (BQ, 1)
-        chosen_i = jnp.take_along_axis(ci, am, axis=1)             # (BQ, 1)
+        hit = pos_iota == am
+        # the id at the argmax as a masked max: exactly one column hits
+        chosen_i = jnp.max(jnp.where(hit, cand_i, -1), axis=1,
+                           keepdims=True)                          # (BQ, 1)
         # exhausted rows (max == NEG sentinel) re-pick an already-taken
         # position whose id column still holds a real row id; emit -1 so
         # empty output slots never alias a real candidate
         chosen_i = jnp.where(m > NEG / 2, chosen_i, -1)
-        acc_s = jax.lax.dynamic_update_slice(acc_s, m, (0, t))
-        acc_i = jax.lax.dynamic_update_slice(acc_i, chosen_i, (0, t))
-        hit = pos_iota == am
+        # write output column t with a select on a column iota
+        acc_s = jnp.where(col == t, m, acc_s)
+        acc_i = jnp.where(col == t, chosen_i, acc_i)
         cs = jnp.where(hit, NEG, cs)
-        return cs, ci, acc_s, acc_i
+        return cs, acc_s, acc_i
 
-    _, _, acc_s, acc_i = jax.lax.fori_loop(
-        0, k, body, (cand_s, cand_i, acc_s, acc_i))
+    _, acc_s, acc_i = jax.lax.fori_loop(0, k, body, (cand_s, acc_s, acc_i))
     return acc_s, acc_i
 
 
@@ -77,7 +86,7 @@ def _knn_kernel(q_ref, s_ref, out_s_ref, out_i_ref, *, k: int, bn: int):
 
 
 def knn_topk_pallas(queries, support, k: int, *, block_q: int = 128,
-                    block_n: int = 1024, interpret: bool = True):
+                    block_n: int = 1024):
     Q, D = queries.shape
     N, _ = support.shape
     bq = min(block_q, Q)
@@ -100,6 +109,6 @@ def knn_topk_pallas(queries, support, k: int, *, block_q: int = 128,
             jax.ShapeDtypeStruct((Q, k), jnp.float32),
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(queries, support)
     return out_s, out_i

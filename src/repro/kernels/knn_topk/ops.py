@@ -14,9 +14,8 @@ from .kernel import knn_topk_pallas
 from .ref import knn_topk_reference
 
 
-@functools.partial(jax.jit, static_argnames=("k", "use_pallas", "interpret"))
-def knn_topk(queries, support, k: int, *, use_pallas: bool = False,
-             interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k", "use_pallas"))
+def knn_topk(queries, support, k: int, *, use_pallas: bool = False):
     """queries (Q, D) L2-normalized; support (N, D) raw.
     Returns (scores (Q, k), indices (Q, k)); indices of padded rows never
     appear because padded support rows get -inf similarity."""
@@ -34,8 +33,7 @@ def knn_topk(queries, support, k: int, *, use_pallas: bool = False,
     # pad support with zero rows -> similarity 0; push them to the bottom by
     # padding with a large-negative direction instead: easier to mask after.
     sp = jnp.pad(support, ((0, pn), (0, 0)))
-    scores, idx = knn_topk_pallas(qp, sp, k, block_q=bq, block_n=bn,
-                                  interpret=interpret)
+    scores, idx = knn_topk_pallas(qp, sp, k, block_q=bq, block_n=bn)
     scores, idx = scores[:Q], idx[:Q]
     if pn:
         valid = idx < N

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                 y_ref, st_ref, cs_ref, *, q: int):
@@ -56,7 +58,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     cs_ref[0, 0, 0] = cs.astype(cs_ref.dtype)
 
 
-def ssd_intra_pallas(x, dt, A, Bm, Cm, *, interpret=True):
+def ssd_intra_pallas(x, dt, A, Bm, Cm):
     """x: (B, H, nc, Q, P); dt: (B, H, nc, Q, 1); A: (H,);
     Bm, Cm: (B, G, nc, Q, N).  Returns (y_intra, states, cs)."""
     B, H, nc, Q, P = x.shape
@@ -84,5 +86,5 @@ def ssd_intra_pallas(x, dt, A, Bm, Cm, *, interpret=True):
             jax.ShapeDtypeStruct((B, H, nc, P, N), jnp.float32),
             jax.ShapeDtypeStruct((B, H, nc, Q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x, dt, A, Bm, Cm)

@@ -10,9 +10,8 @@ import jax.numpy as jnp
 from .kernel import ssd_intra_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, initial_state=None,
-             interpret=True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, initial_state=None):
     """Same contract as ssd_reference: x (B,S,H,P), dt (B,S,H), A (H,),
     Bm/Cm (B,S,G,N) -> (y (B,S,H,P) f32, final_state (B,H,P,N) f32)."""
     B, S, H, P = x.shape
@@ -27,7 +26,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, initial_state=None,
 
     y_intra, states, cs = ssd_intra_pallas(
         xr.astype(jnp.float32), dtr.astype(jnp.float32), A.astype(jnp.float32),
-        Br.astype(jnp.float32), Cr.astype(jnp.float32), interpret=interpret)
+        Br.astype(jnp.float32), Cr.astype(jnp.float32))
 
     cs = cs[..., 0]                                  # (B,H,nc,Q)
     chunk_decay = jnp.exp(cs[..., -1])               # (B,H,nc)

@@ -8,6 +8,7 @@ while tests and benches must see the single real device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,15 +16,22 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = ("pod", "data", "model") — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):
     """Small virtual mesh for CPU integration tests
     (requires xla_force_host_platform_device_count >= n_data*n_model*pod)."""
     if pod:
-        return jax.make_mesh((pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all Auto: the model code places arrays with
+    `with_sharding_constraint` and lets XLA propagate the rest, which
+    Explicit axes (the `jax.make_mesh` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -1,7 +1,8 @@
-"""Routed-serving driver: build a pool of reduced-config engines, fit a
-spec-addressed router on a synthetic routing benchmark projected into the
-query encoder's embedding space, then serve a stream of text requests at a
-per-request cost/quality lambda.
+"""Routed-serving driver: build a pool of engines (reduced smoke configs,
+or the published ones with ``--published``), fit a spec-addressed router on
+a synthetic routing benchmark projected into the query encoder's embedding
+space, then serve a stream of text requests at a per-request cost/quality
+lambda.
 
   PYTHONPATH=src python -m repro.launch.serve --pool qwen3-4b mamba2-370m \
       h2o-danube-1.8b --requests 12 --router knn10 --save-artifact /tmp/r
@@ -25,6 +26,13 @@ from repro.serving.router_service import RouterService
 
 TOPICS = ["python programming", "world history", "algebra proofs",
           "poetry writing", "biology facts"]
+
+
+def pool_config(name: str, published: bool = False):
+    """A pool model's config: its published widths, or the `reduced()`
+    smoke variant of the same architecture."""
+    cfg = get_config(name)
+    return cfg if published else reduced(cfg)
 
 
 def build_support(pool, n=300, seed=0):
@@ -55,13 +63,19 @@ def main(argv=None):
     ap.add_argument("--save-artifact", default=None,
                     help="persist the fitted router here and re-boot the "
                          "service from the artifact before serving")
+    ap.add_argument("--published", action="store_true",
+                    help="build the engines at their published widths "
+                         "instead of the reduced smoke configs")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     engines = {}
     for i, name in enumerate(args.pool):
-        cfg = reduced(get_config(name))
+        cfg = pool_config(name, args.published)
         engines[name] = ServingEngine(cfg, max_slots=2, cache_len=64, seed=i)
-        print(f"[pool] {name}: reduced {cfg.total_blocks()} blocks")
+        print(f"[pool] {name}: {cfg.name} {cfg.total_blocks()} blocks, "
+              f"d_model {cfg.d_model}")
 
     ds = build_support(args.pool)
     pipe = RoutingPipeline(args.router).fit(ds)

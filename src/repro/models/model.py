@@ -11,6 +11,7 @@ Public entry points used by training / serving / dry-run:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -25,7 +26,12 @@ from .layers import dense_init, rmsnorm, rmsnorm_init
 # init
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=1)
 def init_params(key, cfg) -> Dict[str, Any]:
+    """Seeded random parameters for ``cfg``.  Jitted, so each weight is
+    drawn straight into its own (cast) buffer: run op by op, every float32
+    draw would sit beside its cast copy, which at published widths holds
+    gigabytes of temporaries at once."""
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 8)
     p: Dict[str, Any] = {

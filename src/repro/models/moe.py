@@ -152,7 +152,6 @@ def _moe_shard_map(params, cfg, x, mesh):
             y_slots.astype(tokens.dtype))
         return y.reshape(xs.shape), aux
 
-    from jax.experimental.shard_map import shard_map
     # tokens split over data axes on batch AND over model axis on sequence.
     in_specs = (P(), P(model_ax, None, None), P(model_ax, None, None),
                 P(model_ax, None, None), P(data_axes, model_ax, None))
@@ -162,8 +161,8 @@ def _moe_shard_map(params, cfg, x, mesh):
         y, aux = local_fn(router, wg, wu, wd, xs)
         return y, jnp.full((1, 1), aux)
 
-    y, aux = shard_map(wrapper, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)(
+    y, aux = jax.shard_map(wrapper, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)(
         params["router"], params["w_gate"], params["w_up"],
         params["w_down"], x)
     return y, jnp.mean(aux)

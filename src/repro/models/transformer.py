@@ -69,9 +69,10 @@ def group_init(key, cfg, pattern, n_groups, cross=False):
         ks = jax.random.split(k, len(pattern))
         return [_layer_init(ki, cfg, spec, cross=cross)
                 for ki, spec in zip(ks, pattern)]
-    keys = jax.random.split(key, n_groups)
-    per_group = [one(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+    # vmapped over the group keys: one traced init per pattern slot, each
+    # leaf drawn directly at its stacked shape (a python loop plus stack
+    # would trace n_groups copies and hold every group twice at once)
+    return jax.vmap(one)(jax.random.split(key, n_groups))
 
 
 # ---------------------------------------------------------------------------

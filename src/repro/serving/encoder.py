@@ -4,7 +4,7 @@ encoder, mean-pooled.  Stands in for the paper's BERT embedding service
 from __future__ import annotations
 
 import hashlib
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +15,9 @@ from repro.models import model as M
 
 _VOCAB = 8192
 _MAXLEN = 64
+#: texts per encoder dispatch: bounds the activations a support-set build
+#: (1e5+ texts) holds on the device at once
+_CHUNK = 2048
 
 
 def hash_tokenize(text: str, max_len: int = _MAXLEN) -> np.ndarray:
@@ -39,7 +42,7 @@ def _encoder():
 
     @jax.jit
     # repro: allow-jit-cache: _encoder is lru_cached, one cache per process
-    def run(tokens):
+    def run(params, tokens):
         x = params["embed"][tokens]
         pos = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
         from repro.models import transformer as tfm
@@ -48,11 +51,16 @@ def _encoder():
         pooled = (h * mask).sum(1) / jnp.maximum(mask.sum(1), 1.0)
         return pooled
 
-    return run
+    # the weights go in as an argument: closed over, they would be baked
+    # into every compiled batch size as 67 MB of constants, and into each
+    # of its persistent compile-cache entries
+    return partial(run, params)
 
 
 def embed_texts(texts) -> np.ndarray:
     toks = np.stack([hash_tokenize(t) for t in texts])
-    emb = np.array(_encoder()(jnp.asarray(toks)))
+    run = _encoder()
+    emb = np.concatenate([np.asarray(run(jnp.asarray(toks[i:i + _CHUNK])))
+                          for i in range(0, len(toks), _CHUNK)])
     emb /= np.maximum(np.linalg.norm(emb, axis=1, keepdims=True), 1e-9)
     return emb.astype(np.float32)

@@ -49,7 +49,8 @@ Every completion emits ONE structured timing log line (JSON on the
 streamed token, i.e. TTFT) and ``stream`` (first -> last token); `/stats`
 aggregates recent TTFT p50/p99.
 
-Demo boot (reduced-config pool, synthetic support set)::
+Demo boot (reduced-config pool unless ``--published``, synthetic support
+set)::
 
     PYTHONPATH=src python -m repro.serving.gateway --port 8800
     curl -N localhost:8800/v1/chat/completions -d '{...}'
@@ -336,6 +337,8 @@ class Gateway:
         self._next_id = 0
         self.counters = collections.Counter()
         self._ttfts: collections.deque = collections.deque(maxlen=512)
+        #: host-clock seconds per boot stage (filled by `demo_gateway`)
+        self.boot_s: Dict[str, float] = {}
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -984,47 +987,63 @@ class Gateway:
 
 
 # ---------------------------------------------------------------------------
-# demo boot: reduced-config pool + synthetic support set
+# demo boot: engine pool + synthetic support set
 # ---------------------------------------------------------------------------
 
 
 def demo_gateway(pool=("qwen3-4b", "mamba2-370m"), router: str = "knn10",
                  *, n_support: int = 120, seed: int = 0, lam: float = 0.0,
                  engine_timeout_s: float = 10.0, max_slots: int = 4,
-                 state_dir: Optional[str] = None,
+                 state_dir: Optional[str] = None, published: bool = False,
                  **gateway_kw) -> Gateway:
-    """Build an (unstarted) gateway over a pool of reduced-config engines
-    and a router fitted on the synthetic routed-serving support set — the
-    boot used by the example client, the CI smoke script, and the load
-    benchmark.
+    """Build an (unstarted) gateway over a pool of engines and a router
+    fitted on the synthetic routed-serving support set — the boot used by
+    the example client, the smoke scripts, and the load benchmark.
+
+    Engines are built at the `reduced()` smoke width unless ``published``,
+    which builds each at its published config (`repro.configs`) — the
+    width a chip run serves.
 
     ``state_dir`` makes the service durable: observe() batches are
     write-ahead-logged + checkpointed there, and a directory that already
     holds a checkpoint boots through `RouterService.recover` (WAL-suffix
-    replay) instead of refitting — restart = resume."""
+    replay) instead of refitting — restart = resume.
+
+    The returned gateway's ``boot_s`` holds the host-clock seconds of each
+    boot stage: ``engines`` (parameter init dispatch + compile), then
+    ``support`` (embedding the support set) and ``fit`` (router fit), or
+    ``recover``."""
     from pathlib import Path
 
-    from repro.configs import get_config, reduced
-    from repro.launch.serve import build_support
+    from repro.launch.serve import build_support, pool_config
     from .engine import ServingEngine
 
-    engines = {name: ServingEngine(reduced(get_config(name)),
+    t0 = time.perf_counter()
+    engines = {name: ServingEngine(pool_config(name, published),
                                    max_slots=max_slots, cache_len=96,
                                    seed=i)
                for i, name in enumerate(pool)}
+    boot_s = {"engines": time.perf_counter() - t0}
     svc_kw = dict(lam=lam, engine_timeout_s=engine_timeout_s)
+    t0 = time.perf_counter()
     if state_dir and (Path(state_dir) / "checkpoints").exists() and \
             any((Path(state_dir) / "checkpoints").iterdir()):
         svc = RouterService.recover(state_dir, engines, **svc_kw)
+        boot_s["recover"] = time.perf_counter() - t0
     else:
         durability = None
         if state_dir:
             from .durability import DurabilityManager
             durability = DurabilityManager(state_dir)
         ds = build_support(list(pool), n=n_support, seed=seed)
+        boot_s["support"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
         svc = RouterService(router, engines, ds=ds, seed=seed,
                             durability=durability, **svc_kw)
-    return Gateway(svc, **gateway_kw)
+        boot_s["fit"] = time.perf_counter() - t0
+    gw = Gateway(svc, **gateway_kw)
+    gw.boot_s = boot_s
+    return gw
 
 
 def main(argv=None) -> None:
@@ -1045,11 +1064,16 @@ def main(argv=None) -> None:
                          "replay instead of refitting")
     ap.add_argument("--drain-timeout", type=float, default=30.0,
                     help="SIGTERM graceful-drain budget in seconds")
+    ap.add_argument("--published", action="store_true",
+                    help="build the engines at their published widths "
+                         "instead of the reduced smoke configs")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     gw = demo_gateway(tuple(args.pool), args.router, lam=args.lam,
-                      state_dir=args.state_dir,
+                      state_dir=args.state_dir, published=args.published,
                       host=args.host, port=args.port)
     with gw:
         gw.install_signal_handlers()

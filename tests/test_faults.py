@@ -439,6 +439,10 @@ def test_chaos_outage_recovery_no_wave_lost(watchdog):
 
     def observe_worker():
         feed = _routing_ds(names, n=10, seed=3)
+        # the feedback agrees that m1 is best, so whenever it lands, waves
+        # 1-2 still route to m1 and meet its faults
+        feed.scores[:] = 0.2
+        feed.scores[:, 1] = 0.9
         for _ in range(4):
             svc.observe(feed.embeddings, feed.scores, feed.costs,
                         recluster="background")

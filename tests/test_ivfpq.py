@@ -159,6 +159,22 @@ def test_nbits4_packs_two_codes_per_byte(clustered):
     assert rec >= 0.6, rec                  # coarse codes, exact re-rank
 
 
+def test_adc_pallas_nbits4_matches_decode_oracle(clustered):
+    """The Pallas ADC kernel unpacks two 4-bit codes per byte row; its
+    shortlist must match the decode oracle exactly like the 8-bit case."""
+    q, s, _, _ = clustered
+    index4 = build_ivfpq_index(s, m=8, nbits=4, seed=0)
+    os, oi = ivfpq_adc_reference(
+        q, index4.centroids, index4.anchors, index4.codebooks,
+        index4.codes_cm, index4.ids_cm, index4.inv_cm, K, DEFAULT_NPROBE,
+        index4.m, index4.nbits)
+    sc, ix = ivfpq_topk(q, index4, K, nprobe=DEFAULT_NPROBE, rerank=0,
+                        backend="pallas")
+    np.testing.assert_allclose(np.asarray(sc), np.asarray(os),
+                               rtol=1e-4, atol=1e-5)
+    assert np.mean(np.asarray(ix) == np.asarray(oi)) > 0.99
+
+
 def test_index_bytes_accounting(clustered):
     """The hot PQ index must be several times smaller than the raw-row IVF
     index over the same partition (the ~16x claim, reduced by the shared
@@ -205,7 +221,7 @@ def test_pallas_compiled_smoke_on_tpu(tier):
         index = build_ivfpq_index(s, lane_pad=128, m=16, seed=0)
         run = lambda be, **kw: ivfpq_topk(qj, index, 16, nprobe=4, rerank=4,
                                           backend=be, **kw)
-    sc_c, ix_c = run("pallas", interpret=False)
+    sc_c, ix_c = run("pallas")
     sc_h, ix_h = run("host")
     np.testing.assert_allclose(np.asarray(sc_c), np.asarray(sc_h),
                                rtol=1e-4, atol=1e-5)

@@ -24,7 +24,7 @@ def test_knn_topk_matches_reference(Q, N, D, k):
     q = q / jnp.linalg.norm(q, axis=1, keepdims=True)
     s = jax.random.normal(ks, (N, D))
     rs, ri = knn_topk_reference(q, s, min(k, N))
-    ps, pi = knn_topk(q, s, k, use_pallas=True, interpret=True)
+    ps, pi = knn_topk(q, s, k, use_pallas=True)
     np.testing.assert_allclose(np.asarray(ps), np.asarray(rs),
                                rtol=1e-5, atol=1e-5)
 
@@ -57,7 +57,7 @@ def test_knn_topk_block_boundaries(Q, N, k):
     q = q / jnp.linalg.norm(q, axis=1, keepdims=True)
     s = jax.random.normal(ks, (N, 32))
     rs, ri = knn_topk_reference(q, s, min(k, N))
-    ps, pi = knn_topk(q, s, k, use_pallas=True, interpret=True)
+    ps, pi = knn_topk(q, s, k, use_pallas=True)
     assert ps.shape == (Q, min(k, N)) and pi.shape == (Q, min(k, N))
     np.testing.assert_allclose(np.asarray(ps), np.asarray(rs),
                                rtol=1e-5, atol=1e-5)
@@ -78,7 +78,7 @@ def test_knn_topk_duplicate_rows_tied_scores():
     q = jax.random.normal(jax.random.fold_in(KEY, 9), (6, 16))
     q = q / jnp.linalg.norm(q, axis=1, keepdims=True)
     rs, _ = knn_topk_reference(q, s, 10)
-    ps, pi = knn_topk(q, s, 10, use_pallas=True, interpret=True)
+    ps, pi = knn_topk(q, s, 10, use_pallas=True)
     np.testing.assert_allclose(np.asarray(ps), np.asarray(rs),
                                rtol=1e-5, atol=1e-5)
     # an index and its duplicate refer to the same underlying row

@@ -38,10 +38,14 @@ def test_train_cli_runs():
     assert "loss=" in out.stdout
 
 
-def test_serve_cli_runs():
+def test_serve_cli_runs(tmp_path):
+    # the entry point keeps its compile cache where the environment says
+    # (else inside the checkout)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--requests", "4",
          "--max-new", "3", "--pool", "qwen3-4b", "mamba2-370m"],
-        capture_output=True, text=True, timeout=540, env=ENV, cwd=ROOT)
+        capture_output=True, text=True, timeout=540, cwd=ROOT,
+        env=dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path)))
     assert out.returncode == 0, out.stderr[-2000:]
     assert "[routing mix]" in out.stdout
+    assert any(tmp_path.iterdir())
