@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent, from the profiler trace (averaged over the chips used).  Nothing
+when the trace holds no device operation."""
+
+
+def read(run):
+    if run.trace is None or not run.trace["busy_s"]:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
