@@ -74,6 +74,7 @@ from repro.kernels.knn_ivf.ops import (DEFAULT_DELTA_CAP, DEFAULT_NPROBE,
                                        build_ivf_index, build_ivfpq_index,
                                        ivf_topk, ivfpq_topk)
 from repro.kernels.knn_topk.ops import knn_topk
+from repro.spans import span
 from ..dataset import RoutingDataset
 from .base import Router, gold_labels, normalize_rows
 from .spec import register
@@ -690,7 +691,11 @@ class KNNRouter(Router):
         stage and fuse everything after it (`_serve_tail_jit`), and on
         ``index="exact"`` a non-fused cell routes the brute-force scan as
         its own dispatch ahead of the same tail.  Decisions are identical
-        across cells; only the latency profile differs."""
+        across cells; only the latency profile differs.
+
+        Traced (`repro.spans`) as ``route/dispatch``, the call of the
+        fused, tail-only or sharded program until it returns, and
+        ``route/fetch``, the copy of its outputs to the host."""
         # repro: allow-host: input embeddings arrive as host data
         X = np.atleast_2d(np.asarray(X, np.float32))
         # explicit h2d (jnp.asarray) — passing a raw np/python lambda into
@@ -704,23 +709,28 @@ class KNNRouter(Router):
             search, args = None, None
         else:
             search, args = self._fused_search(eff)
+        rows = len(X)
         if search is None:
             sims, idx = self._neighbors(X, backend=eff)
-            out = _serve_tail_jit(jnp.asarray(sims), jnp.asarray(idx), S, C,
-                                  lam_j, av, weights=self.weights,
-                                  temperature=float(self.temperature))
+            sims, idx = jnp.asarray(sims), jnp.asarray(idx)
+            with span("route/dispatch", rows=rows):
+                out = _serve_tail_jit(sims, idx, S, C, lam_j, av,
+                                      weights=self.weights,
+                                      temperature=float(self.temperature))
+        else:
+            q = jnp.asarray(normalize_rows(X))
+            if qmesh is None:
+                with span("route/dispatch", rows=rows):
+                    out = _serve_fused_jit(
+                        q, lam_j, av, S, C, *args, search=search,
+                        weights=self.weights,
+                        temperature=float(self.temperature))
+            else:
+                out = self._serve_sharded(qmesh, q, lam_j, av, S, C, search,
+                                          args)
+        with span("route/fetch", rows=rows):
             # repro: allow-host: the single end-of-batch materialization
             return tuple(np.asarray(o) for o in out)
-        q = jnp.asarray(normalize_rows(X))
-        if qmesh is None:
-            out = _serve_fused_jit(q, lam_j, av, S, C, *args, search=search,
-                                   weights=self.weights,
-                                   temperature=float(self.temperature))
-        else:
-            out = self._serve_sharded(qmesh, q, lam_j, av, S, C, search,
-                                      args)
-        # repro: allow-host: the single end-of-batch materialization
-        return tuple(np.asarray(o) for o in out)
 
     def _serve_sharded(self, qmesh, q, lam, avail, S, C, search, args):
         """`_serve_fused_jit` with the batch sharded across ``qmesh`` —
@@ -771,7 +781,7 @@ class KNNRouter(Router):
         if pad:
             q = jnp.pad(q, ((0, pad), (0, 0)))
             lam = jnp.pad(lam, (0, pad))
-        with qmesh:
+        with qmesh, span("route/dispatch", rows=qn):
             out = cached(q, lam, *rep_args)
         return tuple(o[:qn] for o in out)
 

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs.base import ATTN_DENSE, ModelConfig
 from repro.models import model as M
+from repro.spans import span
 
 _VOCAB = 8192
 _MAXLEN = 64
@@ -42,7 +43,7 @@ def _encoder():
 
     @jax.jit
     # repro: allow-jit-cache: _encoder is lru_cached, one cache per process
-    def run(params, tokens):
+    def query_encoder(params, tokens):
         x = params["embed"][tokens]
         pos = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
         from repro.models import transformer as tfm
@@ -54,13 +55,28 @@ def _encoder():
     # the weights go in as an argument: closed over, they would be baked
     # into every compiled batch size as 67 MB of constants, and into each
     # of its persistent compile-cache entries
-    return partial(run, params)
+    return partial(query_encoder, params)
 
 
 def embed_texts(texts) -> np.ndarray:
-    toks = np.stack([hash_tokenize(t) for t in texts])
-    run = _encoder()
-    emb = np.concatenate([np.asarray(run(jnp.asarray(toks[i:i + _CHUNK])))
-                          for i in range(0, len(toks), _CHUNK)])
-    emb /= np.maximum(np.linalg.norm(emb, axis=1, keepdims=True), 1e-9)
-    return emb.astype(np.float32)
+    """Unit-norm float32 embeddings of ``texts``, one row each.  Traced as
+    the span ``encode`` with the children ``encode/tokenize``, and
+    ``encode/dispatch`` then ``encode/fetch`` for each chunk
+    (`repro.spans`)."""
+    rows = len(texts)
+    with span("encode", rows=rows):
+        with span("encode/tokenize", rows=rows):
+            toks = np.stack([hash_tokenize(t) for t in texts])
+        run = _encoder()
+        chunks = []
+        # each chunk is fetched before the next is dispatched, so a support
+        # set's build holds one chunk's outputs on the device at a time
+        for i in range(0, len(toks), _CHUNK):
+            part = toks[i:i + _CHUNK]
+            with span("encode/dispatch", rows=len(part)):
+                out = run(jnp.asarray(part))
+            with span("encode/fetch", rows=len(part)):
+                chunks.append(np.asarray(out))
+        emb = np.concatenate(chunks)
+        emb /= np.maximum(np.linalg.norm(emb, axis=1, keepdims=True), 1e-9)
+        return emb.astype(np.float32)

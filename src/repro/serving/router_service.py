@@ -54,6 +54,7 @@ import numpy as np
 from repro.core.dataset import RoutingDataset
 from repro.core.routers import (Router, RouterSpec, load_router, make_router,
                                 spec_of)
+from repro.spans import span
 from . import encoder
 from .engine import IncompleteDrainError, Request, ServingEngine
 from .faults import (CircuitOpenError, DegradationLadder,
@@ -387,25 +388,30 @@ class RouterService:
 
         Returns (choice, s_hat, c_hat, confidence-or-None, lam_r) as numpy.
         ``qmesh`` shards the batch axis across a device mesh (replicated
-        index; bitwise-identical results)."""
+        index; bitwise-identical results).  Traced as the span ``route``
+        (`repro.spans`), from the embeddings as a float32 matrix to the
+        answers on the host."""
         # repro: allow-host: input embeddings arrive as host data
         emb = np.atleast_2d(np.asarray(emb, np.float32))
-        lam_r = self._resolve_lam(lam, len(emb))
-        avail = self.availability_mask()
-        sf = getattr(self.router, "serve_fused", None)
-        if callable(sf):
-            dg = getattr(self.router, "degraded", None)
-            ctx = (dg(self.ladder[degrade]) if degrade and callable(dg)
-                   else contextlib.nullcontext())
-            with ctx:
-                # serve_fused already returns numpy — no further conversion
-                choice, s_hat, c_hat, _, agree = sf(emb, lam_r, qmesh=qmesh,
-                                                    avail=avail)
-            self._check_arity(s_hat)
-            return choice, s_hat, c_hat, agree, lam_r
-        s_hat, c_hat, conf = self._predict_for_serving(emb)
-        choice, lam_r = self._choose(s_hat, c_hat, lam_r, len(emb), avail)
-        return choice, s_hat, c_hat, conf, lam_r
+        with span("route", rows=len(emb)):
+            lam_r = self._resolve_lam(lam, len(emb))
+            avail = self.availability_mask()
+            sf = getattr(self.router, "serve_fused", None)
+            if callable(sf):
+                dg = getattr(self.router, "degraded", None)
+                ctx = (dg(self.ladder[degrade]) if degrade and callable(dg)
+                       else contextlib.nullcontext())
+                with ctx:
+                    # serve_fused already returns numpy, and spans its
+                    # dispatch and fetch as children of this span
+                    choice, s_hat, c_hat, _, agree = sf(
+                        emb, lam_r, qmesh=qmesh, avail=avail)
+                self._check_arity(s_hat)
+                return choice, s_hat, c_hat, agree, lam_r
+            s_hat, c_hat, conf = self._predict_for_serving(emb)
+            choice, lam_r = self._choose(s_hat, c_hat, lam_r, len(emb),
+                                         avail)
+            return choice, s_hat, c_hat, conf, lam_r
 
     def route_legacy(self, emb: np.ndarray, lam=None) -> tuple:
         """The pre-fusion multi-dispatch chain — retrieval dispatch, utility
