@@ -159,12 +159,29 @@ def _serve_tail_jit(sims, idx, S, C, lam, avail, *, weights: str,
     dispatch: utility, confidence, and per-request-lambda availability-
     masked selection fused.  The inner calls are the same jitted kernels
     the legacy path runs separately, preserved as subcomputations —
-    identical numerics, one device sync instead of three."""
+    identical numerics, one device sync instead of three.
+
+    The five answers leave as ONE float32 ``(Q, 3 + 2M)`` buffer, so the
+    host copies it back once: column 0 is ``choice``'s int32 bits
+    (bit-cast, not converted), then ``s_hat`` and ``c_hat`` (M columns
+    each), then ``kth`` and ``agree``.  `_unpack_route` splits it."""
     s_hat, c_hat = _utility_jit(sims, idx, S, C, weights=weights,
                                 temperature=temperature)
     kth, agree = _confidence_jit(sims, idx, S)
     choice, _ = _select_jit(s_hat, c_hat, lam, avail)
-    return choice, s_hat, c_hat, kth, agree
+    bits = jax.lax.bitcast_convert_type(choice, jnp.float32)
+    return jnp.concatenate([bits[:, None], s_hat, c_hat, kth[:, None],
+                            agree[:, None]], axis=1)
+
+
+def _unpack_route(buf: np.ndarray, rows: int):
+    """(choice, s_hat, c_hat, kth, agree) as numpy views of the first
+    ``rows`` rows of `_serve_tail_jit`'s packed buffer (the sharded branch
+    pads the batch)."""
+    buf = buf[:rows]
+    m = (buf.shape[1] - 3) // 2
+    return (buf[:, 0].view(np.int32), buf[:, 1:1 + m],
+            buf[:, 1 + m:1 + 2 * m], buf[:, -2], buf[:, -1])
 
 
 @functools.partial(jax.jit, static_argnames=("search", "weights",
@@ -176,7 +193,8 @@ def _serve_fused_jit(queries, lam, avail, S, C, *search_args, search,
     weighted utility, confidence diagnostics, and per-request-lambda
     availability-masked selection.  ``search`` is a cached
     `functools.partial` of a module-level jitted search (static by
-    identity, so the jit cache is stable across calls)."""
+    identity, so the jit cache is stable across calls).  Returns
+    `_serve_tail_jit`'s packed buffer."""
     sims, idx = search(queries, *search_args)
     return _serve_tail_jit(sims, idx, S, C, lam, avail, weights=weights,
                            temperature=temperature)
@@ -669,7 +687,9 @@ class KNNRouter(Router):
         jit (`_serve_fused_jit`).  Returns numpy
         (choice, s_hat, c_hat, kth_sim, agreement) — bitwise identical to
         running `predict_with_confidence` and the batched utility argmax
-        separately, because both paths call the same jitted kernels.
+        separately, because both paths call the same jitted kernels.  The
+        program hands them back packed in one buffer, copied to the host
+        once and split into read-only views (`_unpack_route`).
 
         Backends that need a host stage (raw-IVF host traversal, pallas
         tile planning, an index-sharding mesh) keep their retrieval step
@@ -695,7 +715,8 @@ class KNNRouter(Router):
 
         Traced (`repro.spans`) as ``route/dispatch``, the call of the
         fused, tail-only or sharded program until it returns, and
-        ``route/fetch``, the copy of its outputs to the host."""
+        ``route/fetch``, the one copy of the packed outputs to the host,
+        whose ``buffers`` counts the arrays copied (1)."""
         # repro: allow-host: input embeddings arrive as host data
         X = np.atleast_2d(np.asarray(X, np.float32))
         # explicit h2d (jnp.asarray) — passing a raw np/python lambda into
@@ -728,9 +749,10 @@ class KNNRouter(Router):
             else:
                 out = self._serve_sharded(qmesh, q, lam_j, av, S, C, search,
                                           args)
-        with span("route/fetch", rows=rows):
+        with span("route/fetch", rows=rows, buffers=1):
             # repro: allow-host: the single end-of-batch materialization
-            return tuple(np.asarray(o) for o in out)
+            buf = np.asarray(out)
+        return _unpack_route(buf, rows)
 
     def _serve_sharded(self, qmesh, q, lam, avail, S, C, search, args):
         """`_serve_fused_jit` with the batch sharded across ``qmesh`` —
@@ -739,7 +761,9 @@ class KNNRouter(Router):
         wrapped callable is cached per (mesh, search), and the replicated
         index arrays are `device_put` onto the mesh ONCE per index version
         — passing host-committed arrays straight in would re-replicate tens
-        of MB on every call, which is slower than not sharding at all."""
+        of MB on every call, which is slower than not sharding at all.
+        Returns the packed buffer with the batch's padding rows, which the
+        host drops after its one copy."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         key = ("qmesh", qmesh, search, self.weights, self.temperature)
         cached = self._dev.get("qmesh_fn")
@@ -757,7 +781,7 @@ class KNNRouter(Router):
             # repro: allow-jit-cache: cached in self._dev under `key` above
             cached = jax.jit(jax.shard_map(
                 local, mesh=qmesh, in_specs=specs,
-                out_specs=tuple(P(axes) for _ in range(5)),
+                out_specs=P(axes),
                 check_vma=False))
             self._dev["qmesh_fn"] = cached
             self._dev["qmesh_key"] = key
@@ -782,8 +806,7 @@ class KNNRouter(Router):
             q = jnp.pad(q, ((0, pad), (0, 0)))
             lam = jnp.pad(lam, (0, pad))
         with qmesh, span("route/dispatch", rows=qn):
-            out = cached(q, lam, *rep_args)
-        return tuple(o[:qn] for o in out)
+            return cached(q, lam, *rep_args)
 
     # ---- artifact contract: don't store the support rows twice ----
     def state_dict(self):

@@ -18,8 +18,9 @@ The routed hop's spans (read from a trace by ``bench/harness/spans.py``):
   host normalisation.
 * ``route`` -- `repro.serving.router_service.RouterService.route_fused`;
   its children ``route/dispatch`` (the call of the fused, tail-only or
-  sharded program until it returns) and ``route/fetch`` (the copy of its
-  outputs to the host); its self time is the preparation and the uploads.
+  sharded program until it returns) and ``route/fetch`` (the one copy of
+  the packed outputs to the host; ``buffers`` counts the arrays copied);
+  its self time is the preparation and the uploads.
 
 Every span carries ``rows``, the batch size.  This module sits outside
 ``repro.serving`` because `repro.core.routers.knn` uses it, and the

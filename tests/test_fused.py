@@ -380,6 +380,58 @@ def test_route_fused_qmesh_sharding_bitwise(ds):
     np.testing.assert_array_equal(conf_f, conf_u)
 
 
+BRANCH_BACKEND = {"fused": "fused", "tail": "host", "sharded": "fused"}
+
+
+@pytest.mark.parametrize("m", [2, 3])
+@pytest.mark.parametrize("nq", [1, 8])
+@pytest.mark.parametrize("branch", ["fused", "tail", "sharded"])
+@pytest.mark.parametrize("index", ["exact", "ivf", "ivfpq"])
+def test_serve_fused_packs_one_buffer(ds, monkeypatch, index, branch, nq, m):
+    """Every branch's program hands back ONE float32 (Q, 3 + 2M) buffer,
+    and `serve_fused` splits it into today's five answers (dtypes and
+    shapes as before), bitwise equal to the legacy chain's."""
+    from jax.sharding import Mesh
+    import repro.core.routers.knn as knn_mod
+    models = MODELS[:m]
+    sub = RoutingDataset("fused-m", ds.embeddings, ds.scores[:, :m],
+                         ds.costs[:, :m], models)
+    r = KNNRouter(k=7, index=index, backend=BRANCH_BACKEND[branch]).fit(sub)
+    svc = RouterService(r, {n: None for n in models}, lam=0.5)
+    mesh = (Mesh(np.array(jax.devices()[:1]), ("q",))
+            if branch == "sharded" else None)
+    seen = []
+    for name in ("_serve_fused_jit", "_serve_tail_jit"):
+        def spy(*a, _fn=getattr(knn_mod, name), _name=name, **kw):
+            out = _fn(*a, **kw)
+            seen.append((_name, out))
+            return out
+        monkeypatch.setattr(knn_mod, name, spy)
+    X = sub.part("test")[0][:nq]
+    lam = np.random.default_rng(nq + m).uniform(0, 2, nq).astype(np.float32)
+    got = r.serve_fused(X, lam, qmesh=mesh)
+
+    # the sharded branch's tail runs inside its traced program
+    top = "_serve_fused_jit" if branch == "fused" else "_serve_tail_jit"
+    outs = [o for name, o in seen if name == top]
+    assert len(outs) == 1
+    assert isinstance(outs[0], jax.Array)
+    assert outs[0].shape == (nq, 3 + 2 * m)
+    assert outs[0].dtype == jnp.float32
+
+    assert len(got) == 5
+    for a, dtype, shape in zip(got, (np.int32,) + (np.float32,) * 4,
+                               ((nq,), (nq, m), (nq, m), (nq,), (nq,))):
+        assert a.dtype == dtype and a.shape == shape
+    choice, s_hat, c_hat, kth, agree = got
+    cl, sl, chl, agree_l, _ = svc.route_legacy(X, lam)
+    np.testing.assert_array_equal(choice, cl)
+    np.testing.assert_array_equal(s_hat, sl)
+    np.testing.assert_array_equal(c_hat, chl)
+    np.testing.assert_array_equal(kth, r.predict_with_confidence(X)[2])
+    np.testing.assert_array_equal(agree, agree_l)
+
+
 def test_spec_backend_key(ds):
     r = make_router("knn5-ivfpq@backend=host")
     assert r.backend == "host" and r.exec_backend == "host"

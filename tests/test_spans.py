@@ -108,7 +108,7 @@ def test_encode_spans_nest_with_rows(tmp_path):
 def test_route_spans_nest_with_rows_on_every_branch(ds, branch, tmp_path):
     """``route`` wraps `route_fused`; its children ``route/dispatch`` and
     ``route/fetch`` come from `serve_fused` on the fused, tail-only and
-    batch-sharded branches alike."""
+    batch-sharded branches alike, and the fetch copies one buffer."""
     from jax.sharding import Mesh
     svc = service(ds, index="exact",
                   **({"backend": "host"} if branch == "tail" else {}))
@@ -127,6 +127,7 @@ def test_route_spans_nest_with_rows_on_every_branch(ds, branch, tmp_path):
     disp, = named(kids, "route/dispatch")
     fetch, = named(kids, "route/fetch")
     assert disp[3] <= fetch[2]
+    assert fetch[4]["buffers"] == 1
 
 
 def test_answers_are_bitwise_those_of_the_unspanned_calls(ds, tmp_path):
