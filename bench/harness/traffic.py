@@ -4,8 +4,10 @@ requests out.  No JAX here: the load generator's process imports this.
 Every seed gets the same multiset of sizes and gaps, in another order:
 sizes and gaps are the quantiles of the mix's distributions at fixed
 points, and ``--seed`` shuffles them, picks the filler words and the
-order of topics.  So two seeds offer the same work, and runs of different
-seeds spread no wider than runs of one.
+order of topics.  An open loop's gaps are scaled to fill the window, so
+every one of its requests is due inside it and every seed offers the same
+number.  So two seeds offer the same work, and runs of different seeds
+spread no wider than runs of one.
 
 A mix file holds:
 
@@ -77,48 +79,46 @@ def _shares(values: List, n: int) -> List:
 
 
 def request_count(mix: Dict, seconds: float) -> int:
-    """Requests a schedule holds: an open loop's arrivals over twice the
-    window (the window takes what falls inside it); a closed loop's pool
-    of requests, more than its clients can finish."""
+    """Requests a schedule holds: an open loop's arrivals in the window,
+    its rate times its seconds; a closed loop's pool of requests, more
+    than its clients can finish."""
     if "requests" in mix:
         return int(mix["requests"])
     if mix["loop"] == "open":
         return min(MAX_REQUESTS,
-                   max(1, math.ceil(2 * mix["arrivals"]["rate_per_s"]
-                                    * seconds)))
+                   max(1, round(mix["arrivals"]["rate_per_s"] * seconds)))
     return min(MAX_REQUESTS, max(64, 40 * int(mix["clients"])
                                  * max(1, math.ceil(seconds / 10))))
 
 
-def _arrivals(mix: Dict, n: int, rng: random.Random) -> List[float]:
+def _starts(n: int, seconds: float, rng: random.Random) -> List[float]:
+    """``n`` arrival times from 0 on: exponential gaps at fixed quantiles,
+    in the seed's order, scaled to sum to the window, so the last comes one
+    gap before its close."""
+    gaps = [-math.log(1.0 - q) for q in _quantiles(n)]
+    scale = seconds / sum(gaps)
+    rng.shuffle(gaps)
+    out, t = [], 0.0
+    for g in gaps:
+        out.append(t)
+        t += g * scale
+    return out
+
+
+def _arrivals(mix: Dict, n: int, rng: random.Random,
+              seconds: float) -> List[float]:
     arr = mix["arrivals"]
-    rate = float(arr["rate_per_s"])
     if arr["kind"] == "poisson":
-        gaps = [-math.log(1.0 - q) / rate for q in _quantiles(n)]
-        rng.shuffle(gaps)
-        out, t = [], 0.0
-        for g in gaps:
-            out.append(t)
-            t += g
-        return out
+        return _starts(n, seconds, rng)
     if arr["kind"] == "bursts":
         lo, hi = int(arr["burst_min"]), int(arr["burst_max"])
-        sizes = [lo + i % (hi - lo + 1) for i in range(n)]
+        sizes = []
+        while sum(sizes) < n:
+            sizes.append(lo + len(sizes) % (hi - lo + 1))
+        sizes[-1] -= sum(sizes) - n
         rng.shuffle(sizes)
-        mean_burst = (lo + hi) / 2.0
-        n_bursts = 0
-        total = 0
-        while total < n:
-            total += sizes[n_bursts]
-            n_bursts += 1
-        gaps = [-math.log(1.0 - q) * mean_burst / rate
-                for q in _quantiles(n_bursts)]
-        rng.shuffle(gaps)
-        out, t = [], 0.0
-        for b in range(n_bursts):
-            out.extend([t] * sizes[b])
-            t += gaps[b]
-        return out[:n]
+        starts = _starts(len(sizes), seconds, rng)
+        return [t for t, size in zip(starts, sizes) for _ in range(size)]
     raise ValueError(f"unknown arrival kind {arr['kind']!r}")
 
 
@@ -136,7 +136,8 @@ def schedule(mix: Dict, seed: int, seconds: float) -> List[Dict]:
     topics = _shares(list(range(len(TOPICS))), n)
     for xs in (lams, toks, words, topics):
         rng.shuffle(xs)
-    due = _arrivals(mix, n, rng) if mix["loop"] == "open" else [0.0] * n
+    due = (_arrivals(mix, n, rng, seconds) if mix["loop"] == "open"
+           else [0.0] * n)
     reqs = []
     for i in range(n):
         topic = TOPICS[topics[i]].split()

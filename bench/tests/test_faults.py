@@ -72,7 +72,8 @@ def test_sound_run_reports_every_check(monkeypatch):
     res = bench_run(monkeypatch)
     assert res["attempted"] > 0 and res["failed"] == 0
     assert res["checks"]["route_regret_mean"]["value"] == 0.0
-    assert {"ttft_p50_ms", "ttft_p90_ms", "setup_s"} <= set(res["metrics"])
+    assert {"ttft_p50_ms", "ttft_p90_ms", "routes_per_s",
+            "setup_s"} <= set(res["metrics"])
     assert list(res)[-1] == "checks"
 
 

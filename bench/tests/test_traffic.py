@@ -28,6 +28,9 @@ def test_seeds_share_sizes_and_gaps(name):
             diff = collections.Counter(gaps(a)) - collections.Counter(gaps(b))
             assert sum(diff.values()) <= 1
         assert all(y["due"] >= x["due"] for x, y in zip(a, a[1:]))
+        # every request of an open loop is due inside the window
+        assert len(a) == round(mix["arrivals"]["rate_per_s"] * 45)
+        assert all(0 <= r["due"] < 45 for r in a + b)
     assert a != b
     assert a == traffic.schedule(mix, 2 ** 31 + 11, 45)
 
@@ -43,7 +46,7 @@ def test_sizes_follow_the_mix():
     assert {r["lam"] for r in reqs} == {0.0, 100.0}
     rate = mix["arrivals"]["rate_per_s"]
     in_window = sum(r["due"] < 45 for r in reqs)
-    assert abs(in_window - rate * 45) < 0.25 * rate * 45
+    assert in_window == len(reqs) == round(rate * 45)
 
 
 def test_bursts_arrive_together():
