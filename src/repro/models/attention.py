@@ -112,18 +112,29 @@ def _vec_pos(pos, B):
     return jnp.broadcast_to(pos, (B,))
 
 
-def _row_update(cache, new, slots):
-    """cache: (B, S, ...); new: (B, 1, ...); slots: (B,) — per-row insert."""
+def _row_update(cache, new, slots, update=None):
+    """cache: (B, S, ...); new: (B, 1, ...); slots: (B,) — per-row insert.
+    ``update`` (B,) bool, where given: a row that is False writes its old
+    entry back, so its cache is left bit for bit."""
+    if update is not None:
+        old = jax.vmap(lambda c, s: jax.lax.dynamic_slice_in_dim(
+            c, s, 1, axis=0))(cache, slots)
+        new = jnp.where(update.reshape((-1,) + (1,) * (new.ndim - 1)),
+                        new.astype(cache.dtype), old)
+
     def one(c, n, s):
         return jax.lax.dynamic_update_slice_in_dim(c, n, s, axis=0)
     return jax.vmap(one)(cache, new, slots)
 
 
-def gqa_decode(params, cfg, x, cache_k, cache_v, pos, *, window=0):
+def gqa_decode(params, cfg, x, cache_k, cache_v, pos, *, window=0,
+               update=None):
     """Single-token decode.  x:(B,1,D); cache:(B,S,KV,hd);
     pos: scalar or per-slot (B,) int32 positions (continuous batching).
 
     With ``window>0`` the cache is a ring buffer of size ``window``.
+    ``update`` (B,) bool, where given: the slots whose cache this step
+    writes; the others keep theirs.
     Returns (y, new_cache_k, new_cache_v).
     """
     B = x.shape[0]
@@ -132,8 +143,8 @@ def gqa_decode(params, cfg, x, cache_k, cache_v, pos, *, window=0):
     q, k, v = _project_qkv(params, cfg, x, pos[:, None])
     S = cache_k.shape[1]
     slot = jnp.where(window > 0, pos % jnp.maximum(S, 1), pos)
-    cache_k = _row_update(cache_k, k, slot)
-    cache_v = _row_update(cache_v, v, slot)
+    cache_k = _row_update(cache_k, k, slot, update)
+    cache_v = _row_update(cache_v, v, slot, update)
 
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
@@ -263,7 +274,7 @@ def mla_full(params, cfg, x, positions, *, return_kv=False):
     return y
 
 
-def mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos):
+def mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos, update=None):
     """Naive MLA decode: expand the WHOLE latent cache to per-head K/V every
     step (what a direct port of full-attention decode would do).  Kept as the
     §Perf E baseline — the absorbed path below avoids the O(S·H·d) expansion."""
@@ -274,8 +285,8 @@ def mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos):
     positions = pos[:, None]
     q_nope, q_rope = _mla_q(params, cfg, x, positions)
     c, k_rope = _mla_ckv(params, cfg, x, positions)
-    cache_c = _row_update(cache_c, c, pos)
-    cache_krope = _row_update(cache_krope, k_rope, pos)
+    cache_c = _row_update(cache_c, c, pos, update)
+    cache_krope = _row_update(cache_krope, k_rope, pos, update)
 
     S = cache_c.shape[1]
     k_nope = jnp.einsum("bsr,rh->bsh", cache_c,
@@ -296,12 +307,13 @@ def mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos):
     return y[:, None, :], cache_c, cache_krope
 
 
-def mla_decode(params, cfg, x, cache_c, cache_krope, pos):
+def mla_decode(params, cfg, x, cache_c, cache_krope, pos, update=None):
     """Absorbed-matmul MLA decode: attention runs in the kv_lora space so the
     cache is only (B, S, r + rope_dim) — the point of MLA.
-    pos: scalar or per-slot (B,) positions."""
+    pos: scalar or per-slot (B,) positions; ``update`` as in `gqa_decode`."""
     if getattr(cfg, "mla_naive_decode", False):
-        return mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos)
+        return mla_decode_naive(params, cfg, x, cache_c, cache_krope, pos,
+                                update)
     B = x.shape[0]
     H, nd, rd, vd, r = (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
                         cfg.v_head_dim, cfg.kv_lora_rank)
@@ -309,8 +321,8 @@ def mla_decode(params, cfg, x, cache_c, cache_krope, pos):
     positions = pos[:, None]
     q_nope, q_rope = _mla_q(params, cfg, x, positions)          # (B,1,H,*)
     c, k_rope = _mla_ckv(params, cfg, x, positions)             # (B,1,r),(B,1,rd)
-    cache_c = _row_update(cache_c, c, pos)
-    cache_krope = _row_update(cache_krope, k_rope, pos)
+    cache_c = _row_update(cache_c, c, pos, update)
+    cache_krope = _row_update(cache_krope, k_rope, pos, update)
 
     w_uk = params["w_uk"].reshape(r, H, nd)
     q_abs = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),

@@ -155,6 +155,23 @@ def init_caches(cfg, batch, cache_len, long_mode=False, enc_len=0):
                            enc_len=use_enc)
 
 
+def has_recurrent_state(cfg) -> bool:
+    """Whether ``cfg``'s decode caches hold state-space layers' state."""
+    return any(kind == "ssm" for kind, _ in cfg.pattern + cfg.tail_pattern)
+
+
+def reset_slot(caches, slot):
+    """``caches`` with slot ``slot``'s recurrent state (the ``conv`` and
+    ``ssd`` leaves of the state-space layers) set to zero, as a new
+    sequence starts from.  Attention caches are left as they are: a slot
+    attends only to the positions it has written since it started."""
+    def leaf(path, a):
+        if getattr(path[-1], "key", None) in ("conv", "ssd"):
+            return a.at[:, slot].set(0)
+        return a
+    return jax.tree_util.tree_map_with_path(leaf, caches)
+
+
 def fill_cross_cache(params, cfg, caches, enc_out):
     """Project encoder output into every decoder layer's cached cross K/V
     (once per request; replaces per-step recompute)."""
@@ -188,12 +205,15 @@ def prefill(params, cfg, batch, cache_len, long_mode=False):
     return logits
 
 
-def decode_step(params, cfg, caches, token, pos, enc_out=None):
-    """token: (B, 1) int32; pos: scalar int32 position of this token.
+def decode_step(params, cfg, caches, token, pos, enc_out=None, update=None):
+    """token: (B, 1) int32; pos: scalar or per-slot (B,) int32 position of
+    this token.  ``update`` (B,) bool, optional: the slots this step serves;
+    the caches and states of the others are left bit for bit (their logits
+    mean nothing).  Without it every slot updates.
     Returns (logits (B, vocab), new_caches)."""
     x = params["embed"][token]
     x, new_caches = tfm.stack_decode(params["stack"], cfg, caches, x, pos,
-                                     enc_out=enc_out)
+                                     enc_out=enc_out, update=update)
     x = rmsnorm(params["final_norm"], x)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
     return logits[:, 0], new_caches

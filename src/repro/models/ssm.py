@@ -114,8 +114,12 @@ def ssm_state_init(cfg, batch, dtype=jnp.float32):
     }
 
 
-def ssm_decode(params, cfg, x, state):
-    """x: (B,1,D); state dict from ssm_state_init.  Returns (y, new_state)."""
+def ssm_decode(params, cfg, x, state, update=None):
+    """x: (B,1,D); state dict from ssm_state_init.  Returns (y, new_state).
+
+    ``update`` (B,) bool, where given: the slots whose state this step
+    advances; the others keep theirs bit for bit.  The select fuses into
+    the state update, so the state is still read and written once."""
     B = x.shape[0]
     d_in, H, P, G, N, _ = _dims(cfg)
     zxbcdt = jnp.einsum("bsd,de->bse", x, params["in_proj"])
@@ -131,4 +135,9 @@ def ssm_decode(params, cfg, x, state):
     y = y.reshape(B, 1, d_in).astype(x.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z))
     out = jnp.einsum("bse,ed->bsd", y, params["out_proj"])
-    return out, {"conv": conv_state, "ssd": ssd_state}
+    new_state = {"conv": conv_state, "ssd": ssd_state}
+    if update is not None:
+        new_state = jax.tree.map(
+            lambda n, o: jnp.where(update.reshape((-1,) + (1,) * (o.ndim - 1)),
+                                   n.astype(o.dtype), o), new_state, state)
+    return out, new_state

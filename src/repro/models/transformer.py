@@ -216,30 +216,33 @@ def caches_init(cfg, batch, cache_len, long_mode=False, enc_len=0):
     return c
 
 
-def _apply_layer_decode(lp, cfg, spec, x, cache, pos, shared, enc_out):
+def _apply_layer_decode(lp, cfg, spec, x, cache, pos, shared, enc_out,
+                        update=None):
     kind, ffn = spec
     if kind == "attn":
         w = cfg.sliding_window
         ring = w if (w and cache["k"].shape[1] <= w) else 0
         h, ck, cv = attn.gqa_decode(lp["attn"], cfg, rmsnorm(lp["norm1"], x),
-                                    cache["k"], cache["v"], pos, window=ring)
+                                    cache["k"], cache["v"], pos, window=ring,
+                                    update=update)
         x = x + h
         cache = dict(cache, k=ck, v=cv)   # preserves cached cross ck/cv
     elif kind == "mla":
         h, cc, ckr = attn.mla_decode(lp["attn"], cfg, rmsnorm(lp["norm1"], x),
-                                     cache["c"], cache["kr"], pos)
+                                     cache["c"], cache["kr"], pos, update)
         x = x + h
         cache = {"c": cc, "kr": ckr}
     elif kind == "ssm":
         h, cache = ssm_mod.ssm_decode(lp["ssm"], cfg, rmsnorm(lp["norm1"], x),
-                                      cache)
+                                      cache, update)
         x = x + h
     elif kind == "shared_attn":
         w = cfg.shared_attn_window
         ring = w if (w and cache["k"].shape[1] <= w) else 0
         h, ck, cv = attn.gqa_decode(shared["attn"], cfg,
                                     rmsnorm(shared["norm1"], x),
-                                    cache["k"], cache["v"], pos, window=ring)
+                                    cache["k"], cache["v"], pos, window=ring,
+                                    update=update)
         x = x + h
         x = x + mlp(shared["mlp"], rmsnorm(shared["norm2"], x))
         cache = {"k": ck, "v": cv}
@@ -264,7 +267,10 @@ def _apply_layer_decode(lp, cfg, spec, x, cache, pos, shared, enc_out):
     return x, cache
 
 
-def stack_decode(params, cfg, caches, x, pos, enc_out=None):
+def stack_decode(params, cfg, caches, x, pos, enc_out=None, update=None):
+    """One token through the stack.  ``update`` (B,) bool, where given: the
+    slots whose caches and states this step changes; every other slot
+    keeps its own bit for bit.  None: every slot."""
     shared = params.get("shared")
 
     def scan_part(group_params, group_caches, pattern, h):
@@ -273,7 +279,7 @@ def stack_decode(params, cfg, caches, x, pos, enc_out=None):
             new_cs = []
             for i, spec in enumerate(pattern):
                 h, c = _apply_layer_decode(lp[i], cfg, spec, h, cs[i], pos,
-                                           shared, enc_out)
+                                           shared, enc_out, update)
                 new_cs.append(c)
             return h, new_cs
         return maybe_scan(body, h, (group_params, group_caches))

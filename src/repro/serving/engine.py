@@ -4,10 +4,29 @@ framework's prefill/decode steps.
 Requests are admitted into fixed decode slots; each slot tracks its own
 position (the decode step takes per-slot position vectors), so new requests
 join while others are mid-generation — continuous batching without
-recompilation.  Prefill runs the full forward and seeds the slot's KV cache
-by replaying the prompt through decode steps in teacher-forcing mode (exact:
-decode == forward was verified by tests; for long prompts a chunked prefill
-would be the production path and is noted in DESIGN.md).
+recompilation.  Admission zeroes the slot's recurrent state (state-space
+layers) and replays the prompt but its last token through decode steps in
+teacher-forcing mode; the next `step` feeds the last prompt token, and its
+logits give the first token served.  So each prompt token is written once,
+at its own position (for long prompts a chunked prefill would be the
+production path and is noted in DESIGN.md).  The requests that
+`run_until_drained` admits together replay together: dispatch ``t`` feeds
+token ``t`` of every prompt that still has one, so a wave of prompts costs
+as many dispatches as its longest prompt, not their sum.
+
+Every dispatch changes the caches and states of the slots it serves alone:
+the jitted step (``engine_step``) takes a position per slot, and a negative
+one marks a slot the dispatch leaves bit for bit
+(`repro.models.model.decode_step`'s ``update``).  The mask rides in the
+position's sign so that the step keeps its four arguments (params, caches,
+tokens, positions), which wrappers of ``_decode`` (the benchmark's planted
+faults, the reference tests) rely on; the clamped position of an idle slot
+makes each attention layer read and write back that slot's row 0.  A
+replay serves the slots it replays; `step` the active slots, whose greedy
+tokens are taken on the device (``greedy_tokens``) so that only the token
+ids are copied back.  Admissions and decode waves are spanned
+(``engine/prefill``, ``engine/step``; `repro.spans`) and counted in
+`ServingEngine.stats`.
 """
 from __future__ import annotations
 
@@ -21,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
+from repro.spans import span
 
 _log = logging.getLogger(__name__)
 
@@ -78,41 +98,83 @@ class ServingEngine:
         self.caches = M.init_caches(cfg, max_slots, cache_len)
         self.pos = np.full((max_slots,), -1, np.int64)       # next position
         self.slot_req: List[Optional[Request]] = [None] * max_slots
-        self._decode = jax.jit(
-            lambda params, caches, tok, pos: M.decode_step(
-                params, cfg, caches, tok, pos))
-        self.stats = {"decode_steps": 0, "tokens_out": 0, "prefill_tokens": 0}
+        def engine_step(params, caches, tok, pos):
+            # a negative position marks a slot this dispatch does not serve
+            return M.decode_step(params, cfg, caches, tok,
+                                 jnp.maximum(pos, 0), update=pos >= 0)
+
+        def greedy_tokens(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        self._decode = jax.jit(engine_step)
+        self._argmax = jax.jit(greedy_tokens)
+        self._reset = (jax.jit(M.reset_slot, donate_argnums=0)
+                       if M.has_recurrent_state(cfg) else None)
+        #: ``prefill_steps`` counts the dispatches that replay prompts and
+        #: ``prefill_s`` their host seconds (resets, dispatches, until the
+        #: caches are ready); ``decode_steps`` and ``decode_s`` the same for
+        #: `step` (dispatch, and copy of the greedy tokens)
+        self.stats = {"decode_steps": 0, "tokens_out": 0, "prefill_tokens": 0,
+                      "prefill_steps": 0, "prefill_s": 0.0, "decode_s": 0.0}
 
     # ---- slot management ----
     def has_free_slot(self) -> bool:
         return any(r is None for r in self.slot_req)
 
     def admit(self, req: Request) -> bool:
-        for s in range(self.max_slots):
-            if self.slot_req[s] is None:
-                self.slot_req[s] = req
-                req.output_tokens = []
-                req.n_prompt = len(req.prompt_tokens)
-                req.t_submit = time.time()
-                self.pos[s] = 0
-                self._prefill_slot(s, req)
-                return True
-        return False
+        return self._admit([req]) == 1
 
-    def _prefill_slot(self, slot: int, req: Request):
-        """Teacher-forced prompt replay into the slot's cache."""
-        toks = np.asarray(req.prompt_tokens, np.int32)
-        self.stats["prefill_tokens"] += len(toks)
-        batch_tok = np.zeros((self.max_slots, 1), np.int32)
-        for t, tok in enumerate(toks):
-            batch_tok[:] = 0
-            batch_tok[slot, 0] = tok
-            pos_vec = np.maximum(self.pos, 0).astype(np.int32)
-            pos_vec[slot] = t
-            _, self.caches = self._decode(self.params, self.caches,
-                                          jnp.asarray(batch_tok),
-                                          jnp.asarray(pos_vec))
-        self.pos[slot] = len(toks)
+    def _admit(self, reqs: List[Request]) -> int:
+        """Admit ``reqs`` in order into free slots, as many as fit, and
+        replay their prompts together.  Returns how many were admitted."""
+        slots = []
+        for req in reqs:
+            s = next((s for s in range(self.max_slots)
+                      if self.slot_req[s] is None), None)
+            if s is None:
+                break
+            self.slot_req[s] = req
+            req.output_tokens = []
+            req.n_prompt = len(req.prompt_tokens)
+            req.t_submit = time.time()
+            self.pos[s] = 0
+            slots.append(s)
+        if slots:
+            self._prefill_slots(slots)
+        return len(slots)
+
+    def _prefill_slots(self, slots: List[int]):
+        """Zero the slots' recurrent state, then replay each prompt but its
+        last token into its slot's caches: dispatch ``t`` serves the slots
+        whose prompt has a token ``t`` before its last; `step` feeds the
+        last tokens."""
+        toks = [np.asarray(self.slot_req[s].prompt_tokens, np.int32)
+                for s in slots]
+        n_tok = sum(len(t) for t in toks)
+        n_replay = max(len(t) for t in toks) - 1
+        self.stats["prefill_tokens"] += n_tok
+        t0 = time.perf_counter()
+        with span("engine/prefill", engine=self.cfg.name, rows=n_tok):
+            if self._reset is not None:
+                for s in slots:
+                    self.caches = self._reset(self.caches, s)
+            for t in range(n_replay):
+                # fresh host arrays per dispatch: on the CPU an uploaded
+                # array may alias its numpy buffer while the step runs
+                batch_tok = np.zeros((self.max_slots, 1), np.int32)
+                pos_vec = np.full((self.max_slots,), -1, np.int32)
+                for s, tk in zip(slots, toks):
+                    if t < len(tk) - 1:
+                        batch_tok[s, 0] = tk[t]
+                        pos_vec[s] = t
+                _, self.caches = self._decode(self.params, self.caches,
+                                              jnp.asarray(batch_tok),
+                                              jnp.asarray(pos_vec))
+            jax.block_until_ready(self.caches)
+        self.stats["prefill_steps"] += max(n_replay, 0)
+        self.stats["prefill_s"] += time.perf_counter() - t0
+        for s, tk in zip(slots, toks):
+            self.pos[s] = len(tk) - 1
 
     # ---- decode wave over all active slots ----
     def step(self):
@@ -128,20 +190,28 @@ class ServingEngine:
         if not active:
             return
         batch_tok = np.zeros((self.max_slots, 1), np.int32)
+        pos_vec = np.full((self.max_slots,), -1, np.int32)
         for s in active:
             r = self.slot_req[s]
             last = (r.output_tokens[-1] if r.output_tokens
                     else int(r.prompt_tokens[-1]))
             batch_tok[s, 0] = last
-        pos_vec = np.maximum(self.pos, 0).astype(np.int32)
-        logits, self.caches = self._decode(self.params, self.caches,
-                                           jnp.asarray(batch_tok),
-                                           jnp.asarray(pos_vec))
-        logits = np.asarray(logits)
+            pos_vec[s] = self.pos[s]
+        t0 = time.perf_counter()
+        meta = {"engine": self.cfg.name, "rows": len(active)}
+        with span("engine/step", **meta):
+            with span("engine/dispatch", **meta):
+                logits, self.caches = self._decode(self.params, self.caches,
+                                                   jnp.asarray(batch_tok),
+                                                   jnp.asarray(pos_vec))
+                tokens = self._argmax(logits)
+            with span("engine/fetch", **meta):
+                tokens = np.asarray(tokens)
+        self.stats["decode_s"] += time.perf_counter() - t0
         self.stats["decode_steps"] += 1
         for s in active:
             r = self.slot_req[s]
-            nxt = int(np.argmax(logits[s]))
+            nxt = int(tokens[s])
             r.output_tokens.append(nxt)
             self.stats["tokens_out"] += 1
             self.pos[s] += 1
@@ -200,13 +270,16 @@ class ServingEngine:
                     f"request(s) remain unfinished (max_steps={max_steps}); "
                     f"uids={[r.uid for r in survivors]}",
                     survivors=survivors, steps=steps)
-            while pending and self.has_free_slot():
+            wave = []
+            free = sum(r is None for r in self.slot_req)
+            while pending and len(wave) < free:
                 req = pending.pop(0)
                 if req.cancelled:           # never admitted: no slot to free
                     req.error = "cancelled"
                     req.t_finish = time.time()
                     continue
-                self.admit(req)
+                wave.append(req)
+            self._admit(wave)
             self.step()
             steps += 1
         return steps

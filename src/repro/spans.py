@@ -22,9 +22,22 @@ The routed hop's spans (read from a trace by ``bench/harness/spans.py``):
   the packed outputs to the host; ``buffers`` counts the arrays copied);
   its self time is the preparation and the uploads.
 
-Every span carries ``rows``, the batch size.  This module sits outside
-``repro.serving`` because `repro.core.routers.knn` uses it, and the
-serving package imports the routers.
+The serving engine's spans (`repro.serving.engine.ServingEngine`), each
+with ``engine``, the model config's name:
+
+* ``engine/prefill`` -- an admission of one or more requests: their
+  slots' recurrent state zeroed and their prompts but the last tokens
+  replayed together, until the caches are ready; ``rows`` is the prompts'
+  tokens.
+* ``engine/step`` -- one decode wave over the active slots (``rows``); its
+  children ``engine/dispatch`` (the calls of the jitted decode step and of
+  the greedy choice until they return) and ``engine/fetch`` (the copy of
+  the chosen token ids to the host, which waits for the step).
+
+Every span carries ``rows``: the batch size, or the prompts' tokens for
+``engine/prefill``.  This module sits outside ``repro.serving`` because
+`repro.core.routers.knn` uses it, and the serving package imports the
+routers.
 """
 from __future__ import annotations
 

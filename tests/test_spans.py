@@ -59,7 +59,7 @@ def traced(fn, log_dir):
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.split("/")[0] in ("encode", "route"):
+                if ev.name.split("/")[0] in ("encode", "route", "engine"):
                     spans.append(((plane.name, line.name), ev.name,
                                   ev.start_ns, ev.start_ns + ev.duration_ns,
                                   dict(ev.stats)))
@@ -147,6 +147,36 @@ def test_answers_are_bitwise_those_of_the_unspanned_calls(ds, tmp_path):
         np.testing.assert_array_equal(emb, want)
         for a, b in zip(got, ref):
             np.testing.assert_array_equal(a, b)
+
+
+def test_engine_spans_nest_with_engine_and_rows(tmp_path):
+    """One request through `ServingEngine`: ``engine/prefill`` for its
+    admission (``rows`` the prompt's tokens), then one ``engine/step`` per
+    token served, each with an ``engine/dispatch`` then ``engine/fetch``
+    child; every span names the engine."""
+    from repro.configs import get_config, reduced
+    from repro.serving.engine import Request, ServingEngine
+    eng = ServingEngine(reduced(get_config("mamba2-370m")), max_slots=2,
+                        cache_len=16)
+    warm = Request(uid=0, prompt_tokens=np.array([1, 2], np.int32),
+                   max_new_tokens=1)
+    eng.run_until_drained([warm])             # compiled outside the trace
+    req = Request(uid=1, prompt_tokens=np.array([3, 4, 5], np.int32),
+                  max_new_tokens=3)
+    _, spans = traced(lambda: eng.run_until_drained([req]), tmp_path)
+    assert req.done and len(req.output_tokens) == 3
+    name = eng.cfg.name
+    pre, = named(spans, "engine/prefill")
+    assert pre[4]["rows"] == 3 and pre[4]["engine"] == name
+    steps = named(spans, "engine/step")
+    assert len(steps) == 3 and pre[3] <= steps[0][2]
+    for st in steps:
+        assert st[4]["rows"] == 1 and st[4]["engine"] == name
+        kids = children(spans, st)
+        assert [x[1] for x in sorted(kids, key=lambda x: x[2])] == [
+            "engine/dispatch", "engine/fetch"]
+        assert all(x[4]["rows"] == 1 and x[4]["engine"] == name
+                   for x in kids)
 
 
 def test_spans_add_no_host_sync_finding_or_pragma():
